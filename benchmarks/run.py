@@ -67,6 +67,9 @@ def main() -> int:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write a BENCH_<name>.json perf snapshot of this run")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache  # src/ is on the path
+
+    use_compile_cache()
     names = list(MODULES) if not args.only else args.only.split(",")
     print("name,us_per_call,derived")
     failures = 0

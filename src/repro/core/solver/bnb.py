@@ -678,8 +678,6 @@ def solve_milp_batched(
     src: int,
     dst: int,
     goals: np.ndarray,
-    *,
-    iters: int = 40,
 ) -> list[MILPResult]:
     """The §5.1.3 round-down pipeline for a batch of throughput goals.
 
@@ -693,7 +691,10 @@ def solve_milp_batched(
     picked per host (ipm_batch: stacked-LAPACK numpy on CPU-only hosts, the
     vmapped JAX IPM when an accelerator is available).
     """
-    from .ipm_batch import solve_lp_batched_with_fallback
+    from .ipm_batch import (
+        solve_lp_batched_with_fallback,
+        solve_lp_batches_with_fallback,
+    )
 
     struct = milp.structure(top, src, dst)
     goals = np.asarray(goals, dtype=float)
@@ -715,7 +716,7 @@ def solve_milp_batched(
     b0[:, struct.row_4c] = -goals
     b0[:, struct.row_4d] = -goals
     x0, root_fun, root_ok, _ = solve_lp_batched_with_fallback(
-        struct.c, struct.A_ub, b0, struct.A_eq, struct.b_eq, iters=iters
+        struct.c, struct.A_ub, b0, struct.A_eq, struct.b_eq
     )
     alive = root_ok.copy()
     n_frac = x0[:, e : e + v]
@@ -743,6 +744,8 @@ def solve_milp_batched(
             if M_mat is not None:
                 key += (M_mat[k] > 0).tobytes()
             groups.setdefault(key, []).append(k)
+        # every group's batch goes to the batch engine in one hand-over
+        jobs, problems = [], []
         for rows in groups.values():
             r0 = rows[0]
             support = n_mat[r0] > 0
@@ -774,10 +777,11 @@ def solve_milp_batched(
                 rstruct.outflow_c(pat) if objective == "outflow"
                 else pat.c_free
             )
-            x, fun, ok, _ = solve_lp_batched_with_fallback(
-                c_stage, pat.A_ub_free, b, pat.A_eq_free,
-                rstruct.b_eq[pat.keep_eq], iters=iters,
-            )
+            jobs.append((rows, rstruct, keep, triv))
+            problems.append((c_stage, pat.A_ub_free, b, pat.A_eq_free,
+                             rstruct.b_eq[pat.keep_eq]))
+        solved = solve_lp_batches_with_fallback(problems)
+        for (rows, rstruct, keep, triv), (x, fun, ok, _) in zip(jobs, solved):
             good = ok & ~triv
             re = rstruct.n_edges
             for row_local, k in enumerate(rows):

@@ -4,9 +4,9 @@ The planner's hot path (Pareto sweeps, feasibility-repair probes, round-down
 refits) produces *batches* of LPs that share (c, A_ub, A_eq) and differ only
 in b. Two engines solve such a batch:
 
-  * ``engine="jax"``   — the vmapped fixed-iteration IPM in ``ipm_jax.py``.
-    The right choice when an accelerator backs jax: one compiled scan, all
-    samples in flight.
+  * ``engine="jax"``   — the vmapped port of this module's algorithm in
+    ``ipm_jax.py``. The right choice when an accelerator backs jax: one
+    compiled loop, all samples in flight.
   * ``engine="numpy"`` — this module's batched Mehrotra predictor-corrector.
     All per-iteration linear algebra runs through numpy's *stacked* LAPACK
     gufuncs (``np.linalg.solve`` on [B, m, m]), which on CPU-only hosts beat
@@ -28,9 +28,13 @@ import os
 
 import numpy as np
 
+from repro.obs.metrics import REGISTRY
+
 from .ipm import _normal_matrix, _ruiz_equilibrate, solve_lp
 
 _EPS = 1e-11
+# uncertified batch samples re-solved by the sequential reference
+_host_resolves = REGISTRY.counter("planner.batch_host_resolves")
 
 
 def _max_step_batched(v: np.ndarray, dv: np.ndarray) -> np.ndarray:
@@ -264,48 +268,76 @@ def _pick_engine(engine: str) -> str:
     env = os.environ.get("REPRO_BATCH_ENGINE")
     if env in ("numpy", "jax"):
         return env
-    try:
-        import jax
+    import jax
 
-        return "numpy" if jax.default_backend() == "cpu" else "jax"
-    except Exception:  # pragma: no cover - jax is a hard dep elsewhere
-        return "numpy"
+    return "numpy" if jax.default_backend() == "cpu" else "jax"
+
+
+def solve_lp_batches_auto(problems, *, engine: str = "auto"):
+    """Engine-dispatched solve of several LP batches, each
+    ``(c, A_ub, b_ub_batch, A_eq, b_eq)``, without the sequential fallback
+    pass. Returns one (x, fun, ok) per batch, the contract of both engines;
+    ``ok`` is the engine's own KKT certificate. The jax engine shares its
+    device calls across the batches (``ipm_jax.solve_lp_batches``)."""
+    eng = _pick_engine(engine)
+    if eng == "jax":
+        from .ipm_jax import solve_lp_batches
+
+        out = solve_lp_batches(problems)
+    else:
+        out = [solve_lp_batched(*p) for p in problems]
+    # LPs each engine was handed, and those it certified
+    for _, _, ok in out:
+        REGISTRY.counter(f"planner.batch_lps.{eng}").inc(len(ok))
+        REGISTRY.counter(f"planner.batch_certified.{eng}").inc(
+            int(np.count_nonzero(ok))
+        )
+    return out
 
 
 def solve_lp_batched_auto(c, A_ub, b_ub_batch, A_eq, b_eq, *,
-                          engine: str = "auto", iters: int = 40):
-    """Engine-dispatched batch solve without the sequential fallback pass.
+                          engine: str = "auto"):
+    """``solve_lp_batches_auto`` for one batch: (x, fun, ok)."""
+    return solve_lp_batches_auto(
+        [(c, A_ub, b_ub_batch, A_eq, b_eq)], engine=engine
+    )[0]
 
-    Same (x, fun, ok) contract as both engines; ``ok`` is the engine's own
-    KKT certificate."""
-    if _pick_engine(engine) == "jax":
-        from .ipm_jax import solve_lp_batched as jax_batched
 
-        return jax_batched(c, A_ub, b_ub_batch, A_eq, b_eq, iters=iters)
-    return solve_lp_batched(c, A_ub, b_ub_batch, A_eq, b_eq)
+def solve_lp_batches_with_fallback(problems, *, engine: str = "auto"):
+    """Batch solves + per-sample sequential re-solve of uncertified samples.
+
+    Returns one (x, fun, ok, n_fallback) per batch; ``ok`` afterwards means
+    "solved to the sequential numpy reference's standard" — samples still
+    not-ok are genuinely infeasible/unbounded there too.
+    """
+    out = []
+    solved = solve_lp_batches_auto(problems, engine=engine)
+    for (c, A_ub, b_ub_batch, A_eq, b_eq), (x, fun, ok) in zip(
+        problems, solved
+    ):
+        bad = np.flatnonzero(~ok)
+        if bad.size:
+            # jax-backed buffers are read-only
+            x, fun, ok = np.array(x), np.array(fun), np.array(ok)
+        b_eq_arr = (
+            np.asarray(b_eq, np.float64) if b_eq is not None else np.zeros(0)
+        )
+        _host_resolves.inc(len(bad))
+        for i in bad:
+            b_eq_i = b_eq_arr[i] if b_eq_arr.ndim == 2 else b_eq_arr
+            res = solve_lp(c, A_ub, b_ub_batch[i], A_eq, b_eq_i)
+            x[i] = res.x
+            fun[i] = res.fun
+            ok[i] = res.ok
+        out.append((x, fun, ok, len(bad)))
+    return out
 
 
 def solve_lp_batched_with_fallback(
-    c, A_ub, b_ub_batch, A_eq, b_eq, *, engine: str = "auto", iters: int = 40
+    c, A_ub, b_ub_batch, A_eq, b_eq, *, engine: str = "auto"
 ):
-    """Batch solve + per-sample sequential re-solve of uncertified samples.
-
-    Returns (x, fun, ok, n_fallback); ``ok`` afterwards means "solved to the
-    sequential numpy reference's standard" — samples still not-ok are
-    genuinely infeasible/unbounded there too.
-    """
-    x, fun, ok = solve_lp_batched_auto(
-        c, A_ub, b_ub_batch, A_eq, b_eq, engine=engine, iters=iters
-    )
-    bad = np.flatnonzero(~ok)
-    if bad.size:
-        # jax-backed buffers are read-only
-        x, fun, ok = np.array(x), np.array(fun), np.array(ok)
-    b_eq_arr = np.asarray(b_eq, np.float64) if b_eq is not None else np.zeros(0)
-    for i in bad:
-        b_eq_i = b_eq_arr[i] if b_eq_arr.ndim == 2 else b_eq_arr
-        res = solve_lp(c, A_ub, b_ub_batch[i], A_eq, b_eq_i)
-        x[i] = res.x
-        fun[i] = res.fun
-        ok[i] = res.ok
-    return x, fun, ok, len(bad)
+    """``solve_lp_batches_with_fallback`` for one batch:
+    (x, fun, ok, n_fallback)."""
+    return solve_lp_batches_with_fallback(
+        [(c, A_ub, b_ub_batch, A_eq, b_eq)], engine=engine
+    )[0]

@@ -1,21 +1,20 @@
 """Public water-filling wrapper: ragged conn sets -> padded kernel tiles.
 
-Builds the one-hot scatter matrices, pads every axis to the f32 tile
-grid (8 x 128), row-replicates the per-lane vectors, and flips the
-kernel to interpret mode off-TPU. When link contention is disabled
-(``ed_cap is None``) every connection is pinned to a single dummy edge
-with a BIG budget — the edge term then can never bind (BIG / n_conns
-still dwarfs any real VM share), which keeps the kernel free of
+Pads every axis to the f32 tile grid (connection lanes to whole kernel
+tiles, VMs and edges to 128), row-replicates the per-lane vectors, and
+flips the kernel to interpret mode off-TPU. When link contention is
+disabled (``ed_cap is None``) every connection is pinned to a single
+dummy edge with a BIG budget — the edge term then can never bind (BIG /
+n_conns still dwarfs any real VM share), which keeps the kernel free of
 optional operands.
 """
 
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from .waterfill import BIG, waterfill_8x
+from .waterfill import BIG, pad_lanes, waterfill_8x
 
 
 def _interpret() -> bool:
@@ -24,6 +23,13 @@ def _interpret() -> bool:
 
 def _pad128(n: int) -> int:
     return max(128, -(-n // 128) * 128)
+
+
+def lane8(vec, width, fill=0.0, dtype=np.float32):
+    """[n] -> [8, width] row-replicated lane tile, padded with ``fill``."""
+    row = np.full(width, fill, dtype=dtype)
+    row[: len(vec)] = vec
+    return np.broadcast_to(row, (8, width))
 
 
 def waterfill_rates(caps, src, dst, eg_cap, in_cap, eid=None, ed_cap=None,
@@ -36,46 +42,22 @@ def waterfill_rates(caps, src, dst, eg_cap, in_cap, eid=None, ed_cap=None,
     companion to ``ref.masked_maxmin_rates`` (the f64 parity oracle).
     """
     caps = np.asarray(caps, dtype=np.float32)
-    src = np.asarray(src, dtype=np.int32)
-    dst = np.asarray(dst, dtype=np.int32)
     nc = caps.shape[0]
     nv = int(eg_cap.shape[0])
     if active is None:
         active = np.ones(nc, dtype=bool)
-    active = np.asarray(active, dtype=bool)
     if ed_cap is None:
         eid = np.zeros(nc, dtype=np.int32)
         ed_cap = np.full(1, BIG, dtype=np.float32)
-    eid = np.asarray(eid, dtype=np.int32)
-    ed_cap = np.asarray(ed_cap, dtype=np.float32)
-    ne = ed_cap.shape[0]
-
-    ncp, nvp, nep = _pad128(nc), _pad128(nv), _pad128(ne)
-    actf = active.astype(np.float32)
-
-    def onehot(idx, width):
-        m = np.zeros((ncp, width), dtype=np.float32)
-        m[np.arange(nc), idx] = actf
-        return m
-
-    def lane(vec, width, fill=0.0):
-        row = np.full(width, fill, dtype=np.float32)
-        row[: vec.shape[0]] = vec
-        return np.broadcast_to(row, (8, width))
-
-    s_src = onehot(src, nvp)
-    s_dst = onehot(dst, nvp)
-    s_ed = onehot(eid, nep)
+    ne = len(ed_cap)
+    ncp, nvp, nep = pad_lanes(nc), _pad128(nv), _pad128(ne)
     if n_iters is None:
         n_iters = 2 * nv + ne + 4
     rates8 = waterfill_8x(
-        lane(caps, ncp), lane(actf, ncp),
-        lane(np.asarray(eg_cap, dtype=np.float32), nvp, BIG),
-        lane(np.asarray(in_cap, dtype=np.float32), nvp, BIG),
-        lane(ed_cap, nep, BIG),
-        jnp.asarray(s_src), jnp.asarray(s_src.T),
-        jnp.asarray(s_dst), jnp.asarray(s_dst.T),
-        jnp.asarray(s_ed), jnp.asarray(s_ed.T),
+        lane8(caps, ncp), lane8(np.asarray(active, np.float32), ncp),
+        lane8(src, ncp, 0, np.int32), lane8(dst, ncp, 0, np.int32),
+        lane8(eid, ncp, 0, np.int32),
+        lane8(eg_cap, nvp, BIG), lane8(in_cap, nvp, BIG), lane8(ed_cap, nep, BIG),
         n_iters=int(n_iters), interpret=_interpret(),
     )
     return np.asarray(rates8[0, :nc])

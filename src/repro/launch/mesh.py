@@ -7,12 +7,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the AxisType
-    enum) only exist from jax 0.5; the pinned 0.4.37 uses the default."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -9,8 +9,9 @@ segment of the loop runs until the next due event, the host applies it
 (numpy, the exact reference logic, emitting the same Skytrace stream)
 and re-enters. The max-min water-filling step is the masked pure-jnp
 transliteration (``kernels.waterfill.ref.masked_maxmin_rates``, bitwise
-vs the numpy oracle under f64) on CPU, or the Pallas one-hot-matmul
-kernel (``kernels.waterfill``) on TPU backends.
+vs the numpy oracle under f64) on CPU, or the f32 Pallas one-hot-matmul
+kernel (``kernels.waterfill``) on TPU backends up to the VMEM size rule
+of ``_rate_solver_for``.
 
 Exact-semantics notes (each is load-bearing for chunk-for-chunk parity):
 
@@ -40,11 +41,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.ops import segment_sum
 
 from repro.core.plan import MulticastPlan
 from repro.core.topology import GBIT_PER_GB
+from repro.obs.metrics import REGISTRY
 from repro.obs.trace import get_tracer
 
 from .simconfig import SimConfig
@@ -95,13 +96,11 @@ class _Cn(NamedTuple):
     max_events: jnp.ndarray  # i64 scalar
     t_eps: jnp.ndarray  # f64 scalar (events.T_EPS)
     one: jnp.ndarray  # f64 1.0, runtime-traced — FMA defeat (see _step)
-    # pallas solver operands (1-element dummies under "masked")
-    p_ssrc: jnp.ndarray
-    p_ssrc_t: jnp.ndarray
-    p_sdst: jnp.ndarray
-    p_sdst_t: jnp.ndarray
-    p_sed: jnp.ndarray
-    p_sed_t: jnp.ndarray
+    # pallas solver operands, [8, lanes] tiles (1-element dummies under
+    # "masked")
+    p_src8: jnp.ndarray
+    p_dst8: jnp.ndarray
+    p_eid8: jnp.ndarray
     p_eg8: jnp.ndarray
     p_in8: jnp.ndarray
 
@@ -143,23 +142,21 @@ class _St(NamedTuple):
 
 def _compute_rates(st: _St, cn: _Cn, sc: _Sc, active):
     if sc.solver == "pallas":
-        from repro.kernels.waterfill.ops import _interpret
-        from repro.kernels.waterfill.waterfill import waterfill_8x
+        from repro.kernels.waterfill.ops import _interpret, _pad128
+        from repro.kernels.waterfill.waterfill import pad_lanes, waterfill_8x
 
-        nc128 = cn.p_ssrc.shape[0]
+        ncl = pad_lanes(sc.ncp)
 
         def lane(v, width):
             row = jnp.zeros(width, dtype=jnp.float32)
             row = row.at[: v.shape[0]].set(v.astype(jnp.float32))
             return jnp.broadcast_to(row[None, :], (8, width))
 
-        nep = cn.p_sed.shape[1]
         r8 = waterfill_8x(
-            lane(st.rate_eff, nc128), lane(active.astype(jnp.float64), nc128),
-            cn.p_eg8, cn.p_in8, lane(st.edge_cap, nep),
-            cn.p_ssrc, cn.p_ssrc_t, cn.p_sdst, cn.p_sdst_t,
-            cn.p_sed, cn.p_sed_t, n_iters=sc.n_iters,
-            interpret=_interpret(),
+            lane(st.rate_eff, ncl), lane(active, ncl),
+            cn.p_src8, cn.p_dst8, cn.p_eid8,
+            cn.p_eg8, cn.p_in8, lane(st.edge_cap, _pad128(sc.ne)),
+            n_iters=sc.n_iters, interpret=_interpret(),
         )
         return r8[0, : sc.ncp].astype(st.rates.dtype)
     from repro.kernels.waterfill.ref import masked_maxmin_rates
@@ -438,10 +435,6 @@ def _segment(st: _St, cn: _Cn, sc: _Sc) -> _St:
 
 
 # ------------------------------------------------------------------ host side
-def _pad128(n: int) -> int:
-    return max(128, -(-n // 128) * 128)
-
-
 def _build(su, cfg, sched, solver: str):
     """Materialized scenario -> (static key, constants, initial state)."""
     from repro.kernels.waterfill.waterfill import BIG
@@ -487,29 +480,18 @@ def _build(su, cfg, sched, solver: str):
 
     n_iters = 2 * nv + ne + 4
     if solver == "pallas":
-        nc128, nv128, ne128 = _pad128(ncp), _pad128(nv), _pad128(ne)
+        from repro.kernels.waterfill.ops import _pad128, lane8
+        from repro.kernels.waterfill.waterfill import pad_lanes
 
-        def onehot(idx, width):
-            m = np.zeros((nc128, width), dtype=np.float32)
-            m[np.arange(nc), np.asarray(idx)] = 1.0
-            return m
-
-        def lane8(vec, width):
-            row = np.full(width, BIG, dtype=np.float32)
-            row[: vec.shape[0]] = vec
-            return np.broadcast_to(row, (8, width)).copy()
-
-        s_src = onehot(su.conn_src, nv128)
-        s_dst = onehot(su.conn_dst, nv128)
-        s_ed = onehot(su.conn_edge, ne128)
+        ncl, nv128 = pad_lanes(ncp), _pad128(nv)
         pall = (
-            s_src, s_src.T.copy(), s_dst, s_dst.T.copy(),
-            s_ed, s_ed.T.copy(),
-            lane8(su.vm_eg_cap, nv128), lane8(su.vm_in_cap, nv128),
+            lane8(su.conn_src, ncl, 0, np.int32),
+            lane8(su.conn_dst, ncl, 0, np.int32),
+            lane8(su.conn_edge, ncl, 0, np.int32),
+            lane8(su.vm_eg_cap, nv128, BIG), lane8(su.vm_in_cap, nv128, BIG),
         )
     else:
-        z = np.zeros((1, 1), dtype=np.float32)
-        pall = (z, z, z, z, z, z, z, z)
+        pall = (np.zeros((1, 1), dtype=np.float32),) * 5
 
     from .events import T_EPS
 
@@ -545,10 +527,9 @@ def _build(su, cfg, sched, solver: str):
         max_events=jnp.int64(max_events),
         t_eps=jnp.float64(T_EPS),
         one=jnp.float64(1.0),
-        p_ssrc=jnp.asarray(pall[0]), p_ssrc_t=jnp.asarray(pall[1]),
-        p_sdst=jnp.asarray(pall[2]), p_sdst_t=jnp.asarray(pall[3]),
-        p_sed=jnp.asarray(pall[4]), p_sed_t=jnp.asarray(pall[5]),
-        p_eg8=jnp.asarray(pall[6]), p_in8=jnp.asarray(pall[7]),
+        p_src8=jnp.asarray(pall[0]), p_dst8=jnp.asarray(pall[1]),
+        p_eid8=jnp.asarray(pall[2]), p_eg8=jnp.asarray(pall[3]),
+        p_in8=jnp.asarray(pall[4]),
     )
     st = _St(
         now=jnp.float64(0.0), it=jnp.int64(0), events=jnp.int64(0),
@@ -772,6 +753,25 @@ def _finalize(st: _St, su, jobs, cfg, retried, tr):
     return MultiSimResult(jobs=out, time_s=now, events=int(st.events))
 
 
+def _rate_solver_for(platform: str, nc: int, nv: int, ne: int) -> str:
+    """The rate solver for a scenario of nc connections over nv VMs and ne
+    shared edges on a jax ``platform``. On TPU it is the Pallas kernel
+    whenever its VMEM estimate at the padded sizes fits
+    (``waterfill.fits``; up to ~1e5 connection lanes), and the masked jnp
+    solver beyond that. Elsewhere it is always the masked solver: the f64
+    path that is bitwise equal to the numpy engine (the Pallas kernel would
+    run in interpret mode)."""
+    if platform != "tpu":
+        return "masked"
+    from repro.kernels.waterfill.ops import _pad128
+    from repro.kernels.waterfill.waterfill import fits, pad_lanes
+
+    ncp = max(8, -(-nc // 8) * 8)
+    if fits(pad_lanes(ncp), _pad128(nv), _pad128(ne)):
+        return "pallas"
+    return "masked"
+
+
 def simulate_multi_jax(
     jobs,
     faults=(),
@@ -786,7 +786,7 @@ def simulate_multi_jax(
     exec_top=None,
     drain: bool = False,
     _rate_solver: str = "auto",  # "masked" (f64 parity) | "pallas" | auto:
-    # pallas on TPU backends, masked everywhere else
+    # the rule of _rate_solver_for
 ):
     """Accelerator-resident multi-job simulation (``SimConfig`` knobs and
     ``events`` scenarios identical to the other engines; results pinned
@@ -800,22 +800,27 @@ def simulate_multi_jax(
         relay_buffer_chunks=relay_buffer_chunks, seed=seed,
         horizon_s=horizon_s, exec_top=exec_top, drain=drain,
     )
-    solver = _rate_solver
-    if solver == "auto":
-        solver = "pallas" if jax.default_backend() == "tpu" else "masked"
-    if solver not in ("masked", "pallas"):
+    if _rate_solver not in ("auto", "masked", "pallas"):
         raise ValueError(f"unknown rate solver {_rate_solver!r}")
     su = materialize_jobs(
         jobs, seed=cfg.seed, straggler_prob=cfg.straggler_prob,
         straggler_speed=cfg.straggler_speed, exec_top=cfg.exec_top,
     )
+    solver = _rate_solver
+    if solver == "auto":
+        solver = _rate_solver_for(
+            jax.default_backend(), int(su.conn_job.shape[0]),
+            int(su.vm_eg_cap.shape[0]), len(su.edges_used),
+        )
+    # runs per rate solver: which one the size rule picked
+    REGISTRY.counter(f"sim.rate_solver.{solver}").inc()
     sched = sorted_schedule(jobs, faults)
     tr = get_tracer()
     if tr.enabled:
         tr.instant("sim.start", 0.0, jobs=len(jobs), scheduled=len(sched))
     retried = np.zeros(len(jobs), dtype=np.int64)
     vm_alive = np.ones(su.vm_eg_cap.shape[0], dtype=bool)
-    with enable_x64():
+    with jax.enable_x64(True):
         sc, cn, st = _build(su, cfg, sched, solver)
         ptr = 0
         max_events = int(cn.max_events)

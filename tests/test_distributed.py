@@ -31,14 +31,11 @@ def test_ring_allreduce_matches_mean():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.transfer.collective import ring_allreduce_tree
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:
-            from jax.experimental.shard_map import shard_map
         mesh = jax.make_mesh((4, 2), ("pod", "data"))
         def body(x):
             return ring_allreduce_tree({"g": x[0]}, "pod", [0, 2, 1, 3])["g"][None]
-        h = shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-                      check_rep=False)
+        h = jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                          out_specs=P("pod"), check_vma=False)
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 6, 33))
         got = np.asarray(jax.jit(h)(x))
         want = np.broadcast_to(np.mean(np.asarray(x), 0, keepdims=True), x.shape)

@@ -171,8 +171,6 @@ def test_masked_waterfill_bitwise_vs_flowsim_oracle(seed, with_edges):
     """ref.masked_maxmin_rates on padded lanes is BITWISE the flowsim
     numpy water-filler on the compacted set (the f64 parity contract the
     jax sim engine stands on), and dead lanes come back exactly 0.0."""
-    from jax.experimental import enable_x64
-
     from repro.kernels.waterfill.ref import masked_maxmin_rates
     from repro.transfer.flowsim import _maxmin_rates_arr
 
@@ -183,7 +181,7 @@ def test_masked_waterfill_bitwise_vs_flowsim_oracle(seed, with_edges):
         caps[active], src[active], dst[active], eg, inn,
         eid[active] if ed is not None else None, ed,
     )
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(masked_maxmin_rates(
             jnp.asarray(caps), jnp.asarray(src), jnp.asarray(dst),
             jnp.asarray(eg), jnp.asarray(inn), jnp.asarray(eid),

@@ -206,3 +206,29 @@ def test_tied_arrivals_order_is_deterministic(top):
     )
     out, traces = run_engines(jobs, seed=0)
     assert_parity(out, traces)
+
+
+def test_pallas_rate_solver_matches_soa_outcomes(top):
+    """The f32 Pallas rate solver inside the x64 event loop (the TPU path,
+    in interpret mode here) gives the discrete outcomes of soa under a VM
+    kill; times agree to the kernel's f32 tolerance."""
+    from repro.transfer.flowsim_jax import simulate_multi_jax
+
+    jobs = _unicast_jobs(top)
+    faults = [VMFailure(t_s=1.0, job=0, region=top.index(SRC), count=1)]
+    soa = simulate(jobs, faults, engine="soa", seed=0)
+    got = simulate_multi_jax(jobs, faults, seed=0, _rate_solver="pallas")
+    assert sum(j.retried_chunks for j in got.jobs) > 0
+    for a, b in zip(got.jobs, soa.jobs):
+        assert (a.status, a.chunks_delivered) == (b.status, b.chunks_delivered)
+        assert a.time_s == pytest.approx(b.time_s, rel=1e-5)
+
+
+def test_rate_solver_rule():
+    """Pallas on TPU while the kernel's VMEM estimate fits, masked beyond
+    that and on every other platform."""
+    from repro.transfer.flowsim_jax import _rate_solver_for
+
+    assert _rate_solver_for("cpu", 3055, 75, 11) == "masked"
+    assert _rate_solver_for("tpu", 3055, 75, 11) == "pallas"
+    assert _rate_solver_for("tpu", 200_000, 75, 11) == "masked"
