@@ -54,7 +54,6 @@ from typing import Sequence
 import numpy as np
 
 from repro.obs.metrics import REGISTRY
-from repro.obs.trace import get_tracer
 
 from .topology import GBIT_PER_GB, Topology
 
@@ -517,19 +516,12 @@ def structure(top: Topology, src: int, dst: int) -> LPStructure:
     cache = top._lp_struct_cache
     key = (src, dst)
     s = cache.get(key)
-    tr = get_tracer()
     if s is None:
         _lp_cache_misses.inc()
-        if tr.enabled:
-            tr.instant("planner.lp_cache_miss", tr.now_wall(),
-                       track="planner", key=f"{src}->{dst}")
         s = LPStructure(top, src, dst)
         cache[key] = s
     else:
         _lp_cache_hits.inc()
-        if tr.enabled:
-            tr.instant("planner.lp_cache_hit", tr.now_wall(),
-                       track="planner", key=f"{src}->{dst}")
     return s
 
 
@@ -966,19 +958,12 @@ def multicast_structure(
     cache = top._lp_struct_cache
     key = ("mc", src, tuple(int(d) for d in dsts))
     s = cache.get(key)
-    tr = get_tracer()
     if s is None:
         _lp_cache_misses.inc()
-        if tr.enabled:
-            tr.instant("planner.lp_cache_miss", tr.now_wall(),
-                       track="planner", key=f"{src}->mc{list(key[2])}")
         s = MulticastLPStructure(top, src, tuple(int(d) for d in dsts))
         cache[key] = s
     else:
         _lp_cache_hits.inc()
-        if tr.enabled:
-            tr.instant("planner.lp_cache_hit", tr.now_wall(),
-                       track="planner", key=f"{src}->mc{list(key[2])}")
     return s
 
 

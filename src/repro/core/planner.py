@@ -34,7 +34,7 @@ import warnings
 
 import numpy as np
 
-from repro.obs.trace import get_tracer
+from repro.obs.trace import region
 
 from . import milp
 from .plan import MulticastPlan, TransferPlan
@@ -530,18 +530,14 @@ class Planner:
         ``max_throughput``, and a list of ``ParetoPoint`` for the sweeps.
         The eight legacy ``plan_*`` / ``max_*`` / ``pareto_*`` methods are
         deprecated shims over this method."""
-        tr = get_tracer()
-        if not tr.enabled:
-            return self._plan_impl(spec)
-        w0 = tr.now_wall()
         b0 = milp._struct_builds.value
-        result = self._plan_impl(spec)
-        tr.span(
-            "planner.plan", w0, tr.now_wall() - w0, track="planner",
-            objective=spec.objective, src=spec.src,
+        with region(
+            "planner.plan", track="planner", objective=spec.objective,
+            src=spec.src,
             dst=spec.dst if not spec.multicast else ",".join(spec.dsts),
-            struct_builds=int(milp._struct_builds.value - b0),
-        )
+        ) as args:
+            result = self._plan_impl(spec)
+            args["struct_builds"] = int(milp._struct_builds.value - b0)
         return result
 
     def _plan_impl(self, spec: PlanSpec):
@@ -607,44 +603,39 @@ class Planner:
         calls. Everything else (multicast, robust, degraded, exact-mode)
         falls back to the sequential ``plan()`` path, which still rides
         cached structures. Results come back in spec order."""
-        tr = get_tracer()
-        w0 = tr.now_wall() if tr.enabled else 0.0
         out: list = [None] * len(specs)
-        groups: dict[tuple[str, str], list[int]] = {}
-        for i, sp in enumerate(specs):
-            batchable = (
-                sp.objective == "cost_min"
-                and not sp.multicast
-                and (sp.mode or self.mode) == "relaxed"
-                and not sp.degraded_links
-                and not sp.vm_caps
-                and not sp.robustness
-                and sp.tput_scale is None
-                and sp.agg_scale is None
-            )
-            if batchable:
-                groups.setdefault((sp.src, sp.dst), []).append(i)
-            else:
-                out[i] = self.plan(sp)
-        for (src, dst), ix in groups.items():
-            sub, s, t, keep = self._prune(src, dst)
-            goals = np.array([specs[i].goals() for i in ix], dtype=float)
-            batch = solve_milp_batched(sub, s, t, goals)
-            for i, g, res in zip(ix, goals, batch):
-                if not res.ok:
-                    # infeasible-goal corner: re-solve sequentially so the
-                    # caller sees the same degraded status plan() returns
-                    out[i] = self.plan(specs[i])
-                    continue
-                out[i] = self._lift(
-                    sub, keep, src, dst, float(g), specs[i].volume_gb, res
+        with region("planner.plan_cohort", track="planner",
+                    n_specs=len(specs)) as args:
+            groups: dict[tuple[str, str], list[int]] = {}
+            for i, sp in enumerate(specs):
+                batchable = (
+                    sp.objective == "cost_min"
+                    and not sp.multicast
+                    and (sp.mode or self.mode) == "relaxed"
+                    and not sp.degraded_links
+                    and not sp.vm_caps
+                    and not sp.robustness
+                    and sp.tput_scale is None
+                    and sp.agg_scale is None
                 )
-        if tr.enabled:
-            tr.span(
-                "planner.plan_cohort", w0, tr.now_wall() - w0,
-                track="planner", n_specs=len(specs),
-                n_batched_routes=len(groups),
-            )
+                if batchable:
+                    groups.setdefault((sp.src, sp.dst), []).append(i)
+                else:
+                    out[i] = self.plan(sp)
+            for (src, dst), ix in groups.items():
+                sub, s, t, keep = self._prune(src, dst)
+                goals = np.array([specs[i].goals() for i in ix], dtype=float)
+                batch = solve_milp_batched(sub, s, t, goals)
+                for i, g, res in zip(ix, goals, batch):
+                    if not res.ok:
+                        # infeasible-goal corner: re-solve sequentially so the
+                        # caller sees the same degraded status plan() returns
+                        out[i] = self.plan(specs[i])
+                        continue
+                    out[i] = self._lift(
+                        sub, keep, src, dst, float(g), specs[i].volume_gb, res
+                    )
+            args["n_batched_routes"] = len(groups)
         return out
 
     # ------------------------------------------------- deprecated shims

@@ -38,6 +38,8 @@ import math
 
 import numpy as np
 
+from repro.obs.trace import region
+
 from .. import milp
 from ..topology import GBIT_PER_GB
 from .ipm import solve_lp
@@ -712,16 +714,17 @@ def solve_milp_batched(
         ]
 
     # ---- stage 0: root relaxations (batch over the two goal rows of b)
-    b0 = np.tile(struct.b_ub0[None, :], (B, 1))
-    b0[:, struct.row_4c] = -goals
-    b0[:, struct.row_4d] = -goals
-    x0, root_fun, root_ok, _ = solve_lp_batched_with_fallback(
-        struct.c, struct.A_ub, b0, struct.A_eq, struct.b_eq
-    )
-    alive = root_ok.copy()
-    n_frac = x0[:, e : e + v]
-    if not alive.any():
-        return finish()
+    with region("bnb.stage0", track="planner"):
+        b0 = np.tile(struct.b_ub0[None, :], (B, 1))
+        b0[:, struct.row_4c] = -goals
+        b0[:, struct.row_4d] = -goals
+        x0, root_fun, root_ok, _ = solve_lp_batched_with_fallback(
+            struct.c, struct.A_ub, b0, struct.A_eq, struct.b_eq
+        )
+        alive = root_ok.copy()
+        n_frac = x0[:, e : e + v]
+        if not alive.any():
+            return finish()
 
     # Stages 1-4 pin N (and later M), so every solve routes through the exact
     # presolve: rows sharing a (support, edge-mask) reduction solve as one
@@ -833,90 +836,94 @@ def solve_milp_batched(
     # ---- stage 1: feasibility repair — batched max-flow probes, two-phase:
     # floors first (usually enough), then the full bump ladder only for the
     # goals whose floor fell short. Matches the sequential first-feasible pick.
-    live_ix = np.flatnonzero(alive)
-    floors = np.floor(n_frac[live_ix] + _INT_TOL)
-    mf_floor = grouped_pinned(None, floors, None, "outflow")
-    n_int = np.zeros((B, v))
-    flow_cap = np.zeros(B)
-    need_ladder = []
-    for row, i in enumerate(live_ix):
-        if mf_floor[row] >= goals[i] * (1.0 - 1e-6):
-            n_int[i] = floors[row]
-            flow_cap[i] = mf_floor[row]
-        else:
-            need_ladder.append(i)
-    if need_ladder:
-        K = v + 1  # bump ladder + ceil (floor already probed)
-        ladders = np.stack(
-            [_repair_candidates(n_frac[i], top.limit_vm)[1:] for i in need_ladder]
-        )
-        mf = grouped_pinned(
-            None, ladders.reshape(-1, v), None, "outflow"
-        ).reshape(len(need_ladder), K)
-        for row, i in enumerate(need_ladder):
-            feas = np.flatnonzero(mf[row] >= goals[i] * (1.0 - 1e-6))
-            if feas.size == 0:
-                alive[i] = False
-                continue
-            k = int(feas[0])
-            n_int[i] = ladders[row, k]
-            flow_cap[i] = mf[row, k]
-    if not alive.any():
-        return finish()
+    with region("bnb.stage1", track="planner"):
+        live_ix = np.flatnonzero(alive)
+        floors = np.floor(n_frac[live_ix] + _INT_TOL)
+        mf_floor = grouped_pinned(None, floors, None, "outflow")
+        n_int = np.zeros((B, v))
+        flow_cap = np.zeros(B)
+        need_ladder = []
+        for row, i in enumerate(live_ix):
+            if mf_floor[row] >= goals[i] * (1.0 - 1e-6):
+                n_int[i] = floors[row]
+                flow_cap[i] = mf_floor[row]
+            else:
+                need_ladder.append(i)
+        if need_ladder:
+            K = v + 1  # bump ladder + ceil (floor already probed)
+            ladders = np.stack(
+                [_repair_candidates(n_frac[i], top.limit_vm)[1:] for i in need_ladder]
+            )
+            mf = grouped_pinned(
+                None, ladders.reshape(-1, v), None, "outflow"
+            ).reshape(len(need_ladder), K)
+            for row, i in enumerate(need_ladder):
+                feas = np.flatnonzero(mf[row] >= goals[i] * (1.0 - 1e-6))
+                if feas.size == 0:
+                    alive[i] = False
+                    continue
+                k = int(feas[0])
+                n_int[i] = ladders[row, k]
+                flow_cap[i] = mf[row, k]
+        if not alive.any():
+            return finish()
 
     # ---- stage 2: fixed-N min-cost refit at min(goal, maxflow)
-    goal_n = np.minimum(goals, flow_cap * (1.0 - 1e-9))
-    alive &= goal_n > 0
-    live_ix = np.flatnonzero(alive)
-    if live_ix.size == 0:
-        return finish()
-    _, M_frac_all, ok2 = grouped_pinned(
-        goal_n[live_ix], n_int[live_ix], None, "cost"
-    )
-    M_int = np.zeros((B, v, v))
-    for row, i in enumerate(live_ix):
-        if not ok2[row]:
-            alive[i] = False
-            continue
-        M_frac = M_frac_all[row]
-        Mi = np.floor(M_frac + _INT_TOL)
-        _topup_connections(top, M_frac, Mi, n_int[i])
-        M_int[i] = Mi
-    live_ix = np.flatnonzero(alive)
-    if live_ix.size == 0:
-        return finish()
+    with region("bnb.stage2", track="planner"):
+        goal_n = np.minimum(goals, flow_cap * (1.0 - 1e-9))
+        alive &= goal_n > 0
+        live_ix = np.flatnonzero(alive)
+        if live_ix.size == 0:
+            return finish()
+        _, M_frac_all, ok2 = grouped_pinned(
+            goal_n[live_ix], n_int[live_ix], None, "cost"
+        )
+        M_int = np.zeros((B, v, v))
+        for row, i in enumerate(live_ix):
+            if not ok2[row]:
+                alive[i] = False
+                continue
+            M_frac = M_frac_all[row]
+            Mi = np.floor(M_frac + _INT_TOL)
+            _topup_connections(top, M_frac, Mi, n_int[i])
+            M_int[i] = Mi
+        live_ix = np.flatnonzero(alive)
+        if live_ix.size == 0:
+            return finish()
 
     # ---- stage 3: fixed-N+M max-flow probe
-    maxflow3 = grouped_pinned(
-        None, n_int[live_ix], M_int[live_ix], "outflow"
-    )
-    achieved = np.zeros(B)
-    achieved[live_ix] = np.minimum(goal_n[live_ix], maxflow3 * (1.0 - 1e-9))
-    alive &= achieved > 0
-    live_ix = np.flatnonzero(alive)
-    if live_ix.size == 0:
-        return finish()
+    with region("bnb.stage3", track="planner"):
+        maxflow3 = grouped_pinned(
+            None, n_int[live_ix], M_int[live_ix], "outflow"
+        )
+        achieved = np.zeros(B)
+        achieved[live_ix] = np.minimum(goal_n[live_ix], maxflow3 * (1.0 - 1e-9))
+        alive &= achieved > 0
+        live_ix = np.flatnonzero(alive)
+        if live_ix.size == 0:
+            return finish()
 
     # ---- stage 4: fixed-N+M min-cost re-fit of F at the achieved goal
-    F_all, _, ok4 = grouped_pinned(
-        achieved[live_ix], n_int[live_ix], M_int[live_ix], "cost"
-    )
-    for row, i in enumerate(live_ix):
-        if not ok4[row]:
-            alive[i] = False
-            continue
-        F = F_all[row]
-        obj = float(
-            (F * top.price_egress).sum() / GBIT_PER_GB
-            + n_int[i] @ top.price_vm
+    with region("bnb.stage4", track="planner"):
+        F_all, _, ok4 = grouped_pinned(
+            achieved[live_ix], n_int[live_ix], M_int[live_ix], "cost"
         )
-        results[i] = MILPResult(
-            F=F,
-            N=n_int[i].astype(np.int64),
-            M=M_int[i].astype(np.int64),
-            objective=obj,
-            status="optimal",
-            lp_objective=float(root_fun[i]),
-            achieved_tput=float(achieved[i]),
-        )
+        for row, i in enumerate(live_ix):
+            if not ok4[row]:
+                alive[i] = False
+                continue
+            F = F_all[row]
+            obj = float(
+                (F * top.price_egress).sum() / GBIT_PER_GB
+                + n_int[i] @ top.price_vm
+            )
+            results[i] = MILPResult(
+                F=F,
+                N=n_int[i].astype(np.int64),
+                M=M_int[i].astype(np.int64),
+                objective=obj,
+                status="optimal",
+                lp_objective=float(root_fun[i]),
+                achieved_tput=float(achieved[i]),
+            )
     return finish()

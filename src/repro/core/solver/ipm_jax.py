@@ -51,6 +51,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.metrics import REGISTRY
+from repro.obs.trace import region
+
 from .ipm import _ruiz_equilibrate
 
 _EPS = 1e-11
@@ -65,6 +68,16 @@ _MIN_ROWS = 64
 _MIN_COLS = 128
 _MIN_BATCH = 16
 _MAX_BATCH = 128
+
+# the work of the device calls: calls, padded and real rows, trips of the
+# vmapped loop (its slowest row's iterations), rows times trips, and the
+# real rows' own iterations
+_device_calls = REGISTRY.counter("ipm.device_calls")
+_batch_rows = REGISTRY.counter("ipm.batch_rows")
+_batch_rows_real = REGISTRY.counter("ipm.batch_rows_real")
+_loop_trips = REGISTRY.counter("ipm.loop_trips")
+_row_trips = REGISTRY.counter("ipm.row_trips")
+_sample_iters = REGISTRY.counter("ipm.sample_iters")
 
 
 def _build_standard(c, A_ub, A_eq):
@@ -109,7 +122,9 @@ def _solve(A, b, c, rmask, cmask):
     (same start, step rule and stopping rules); every reduction runs over
     the real rows/columns only (``rmask``/``cmask``), and the pad block is
     held at x = s = 1, y = 0, so the real block follows the unpadded
-    iterates. Returns (x, y, s, converged)."""
+    iterates. Returns (x, y, s, converged, iterations): under ``vmap`` the
+    loop runs until its slowest sample stops, and each sample's count
+    says how many of those trips advanced it."""
     m_r = jnp.sum(rmask)
     n_r = jnp.sum(cmask)
     real = cmask > 0
@@ -184,26 +199,27 @@ def _solve(A, b, c, rmask, cmask):
         finished = converged | stalled | last
 
         d = x / s
-        L = _chol(*normal(d), 1e-12)
-        # predictor (affine) step
-        r_xs = x * s
-        rhs = -rb - A @ (d * rc - r_xs / s)
-        dy_a = solve(L, rhs)
-        dx_a = d * (A.T @ dy_a + rc) - r_xs / s
-        ds_a = -(r_xs + s * dx_a) / x
-        a_pri = max_step(x, dx_a)
-        a_dua = max_step(s, ds_a)
-        mu_aff = dot(x + a_pri * dx_a, s + a_dua * ds_a) / n_r
-        sigma = jnp.clip((mu_aff / jnp.maximum(mu, _EPS)) ** 3, 0.0, 1.0)
-        # corrector step (same factor)
-        r_xs = x * s + dx_a * ds_a - sigma * mu
-        rhs = -rb - A @ (d * rc - r_xs / s)
-        dy = solve(L, rhs)
-        dx = d * (A.T @ dy + rc) - r_xs / s
-        dsv = -(r_xs + s * dx) / x
-        eta = jnp.minimum(0.999, 0.9 + 0.09 * it / _MAX_ITER)
-        a_pri = eta * max_step(x, dx)
-        a_dua = eta * max_step(s, dsv)
+        with jax.named_scope("factor"):
+            L = _chol(*normal(d), 1e-12)
+        with jax.named_scope("predictor"):  # affine step
+            r_xs = x * s
+            rhs = -rb - A @ (d * rc - r_xs / s)
+            dy_a = solve(L, rhs)
+            dx_a = d * (A.T @ dy_a + rc) - r_xs / s
+            ds_a = -(r_xs + s * dx_a) / x
+            a_pri = max_step(x, dx_a)
+            a_dua = max_step(s, ds_a)
+            mu_aff = dot(x + a_pri * dx_a, s + a_dua * ds_a) / n_r
+            sigma = jnp.clip((mu_aff / jnp.maximum(mu, _EPS)) ** 3, 0.0, 1.0)
+        with jax.named_scope("corrector"):  # same factor
+            r_xs = x * s + dx_a * ds_a - sigma * mu
+            rhs = -rb - A @ (d * rc - r_xs / s)
+            dy = solve(L, rhs)
+            dx = d * (A.T @ dy + rc) - r_xs / s
+            dsv = -(r_xs + s * dx) / x
+            eta = jnp.minimum(0.999, 0.9 + 0.09 * it / _MAX_ITER)
+            a_pri = eta * max_step(x, dx)
+            a_dua = eta * max_step(s, dsv)
         x2, y2, s2 = hold(
             jnp.maximum(x + a_pri * dx, _EPS), y + a_dua * dy,
             jnp.maximum(s + a_dua * dsv, _EPS),
@@ -218,10 +234,11 @@ def _solve(A, b, c, rmask, cmask):
     zero, no = jnp.int32(0), jnp.bool_(False)
     st = (zero, x, y, s, inf, zero, inf, zero, no, no)
     st = jax.lax.while_loop(cond, body, st)
-    return st[1], st[2], st[3], st[8]
+    return st[1], st[2], st[3], st[8], st[0]
 
 
 # one device call: per-sample A [B, m, n], b [B, m], c [B, n], masks
+# -> per-sample (x, y, s, converged, iterations)
 _solve_batched = jax.jit(jax.vmap(_solve))
 
 
@@ -296,32 +313,43 @@ def solve_lp_batches(problems):
         for lo in range(0, len(todo), _MAX_BATCH):
             part = todo[lo : lo + _MAX_BATCH]
             Bp = _MIN_BATCH if len(part) <= _MIN_BATCH else _MAX_BATCH
-            A = np.zeros((Bp, mp, n_pad))
-            b = np.zeros((Bp, mp))
-            c = np.ones((Bp, n_pad))
-            rmask = np.zeros((Bp, mp))
-            cmask = np.zeros((Bp, n_pad))
-            for j in range(Bp):  # extra rows repeat the call's first sample
-                k, i = part[j] if j < len(part) else part[0]
-                lp = lps[k]
-                A[j, : lp.m, : lp.n] = lp.As
-                b[j, : lp.m] = lp.bs[i]
-                c[j, : lp.n] = lp.cs
-                rmask[j, : lp.m] = 1.0
-                cmask[j, : lp.n] = 1.0
-            with jax.enable_x64(True):
-                res = _solve_batched(*(jnp.asarray(a) for a in (
-                    A, b, c, rmask, cmask)))
-            res = [np.asarray(a) for a in res]
+            with region("ipm.pack", track="planner", rows=Bp):
+                A = np.zeros((Bp, mp, n_pad))
+                b = np.zeros((Bp, mp))
+                c = np.ones((Bp, n_pad))
+                rmask = np.zeros((Bp, mp))
+                cmask = np.zeros((Bp, n_pad))
+                for j in range(Bp):  # extra rows repeat the first sample
+                    k, i = part[j] if j < len(part) else part[0]
+                    lp = lps[k]
+                    A[j, : lp.m, : lp.n] = lp.As
+                    b[j, : lp.m] = lp.bs[i]
+                    c[j, : lp.n] = lp.cs
+                    rmask[j, : lp.m] = 1.0
+                    cmask[j, : lp.n] = 1.0
+                with jax.enable_x64(True):
+                    args = [jnp.asarray(a) for a in (A, b, c, rmask, cmask)]
+            with region("ipm.device_call", track="planner", rows=Bp):
+                with jax.enable_x64(True):
+                    res = _solve_batched(*args)
+                *res, iters = (np.asarray(a) for a in res)
+            trips = int(iters.max())
+            _device_calls.inc()
+            _batch_rows.inc(Bp)
+            _batch_rows_real.inc(len(part))
+            _loop_trips.inc(trips)
+            _row_trips.inc(Bp * trips)
+            _sample_iters.inc(int(iters[: len(part)].sum()))
             for j, (k, i) in enumerate(part):
                 out[k][i] = tuple(a[j] for a in res)
     results = []
-    for lp, rows in zip(lps, out):
-        if not lp.m:
-            results.append((np.zeros((lp.B, lp.c.shape[0])), np.zeros(lp.B),
-                            np.ones(lp.B, dtype=bool)))
-            continue
-        results.append(lp.finish(*(np.stack(a) for a in zip(*rows))))
+    with region("ipm.certify", track="planner"):
+        for lp, rows in zip(lps, out):
+            if not lp.m:
+                results.append((np.zeros((lp.B, lp.c.shape[0])),
+                                np.zeros(lp.B), np.ones(lp.B, dtype=bool)))
+                continue
+            results.append(lp.finish(*(np.stack(a) for a in zip(*rows))))
     return results
 
 

@@ -13,7 +13,9 @@ itself with:
     sim-time; planner / gateway events carry ``perf_counter`` wall time
     re-based to the tracer's start. Disabled (the default) it is a
     shared no-op singleton and instrumented hot paths skip event
-    construction entirely behind ``if tr.enabled:``.
+    construction entirely behind ``if tr.enabled:``. ``region`` is the
+    host layers' span: a JAX profiler annotation of the same name always,
+    and a tracer span while one records.
 
 ``export`` renders a tracer's buffer as Chrome-trace / Perfetto JSON or
 a plain-text timeline; ``python -m repro.obs`` runs a seeded chaos
@@ -31,7 +33,7 @@ from .metrics import (
     REGISTRY,
     get_registry,
 )
-from .trace import Tracer, disable, enable, get_tracer
+from .trace import Tracer, disable, enable, get_tracer, region
 
 __all__ = [
     "Counter",
@@ -44,6 +46,7 @@ __all__ = [
     "enable",
     "get_registry",
     "get_tracer",
+    "region",
     "text_timeline",
     "to_chrome_trace",
     "trace_json",

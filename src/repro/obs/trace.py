@@ -11,7 +11,7 @@ Event timebases, by track:
     with the same seed produce byte-identical traces, and ``flowsim`` /
     ``flowsim_ref`` emit identical sim-event streams (pinned by
     tests/test_obs.py).
-  * ``planner`` / ``gateway`` / ``service`` wall spans —
+  * ``planner`` / ``gateway`` / ``service`` / ``sim-host`` wall spans —
     ``time.perf_counter()`` re-based to the tracer's start
     (``now_wall``); legal under SKY001, nondeterministic by nature.
 
@@ -20,6 +20,12 @@ Instrumented hot paths capture ``tr = get_tracer()`` once and guard
 every emission with ``if tr.enabled:`` so disabled-mode overhead is one
 attribute read (unmeasurable on ``flowsim_bench`` — gated by
 ``BENCH_obs.json``).
+
+:class:`region` is the span primitive of the host layers: it always
+enters a ``jax.profiler.TraceAnnotation`` of the same name, so the span
+lands in any JAX profiler trace on the calling thread's line, nested in
+whatever annotation encloses it, and it also records a wall span here
+while a recording tracer is installed.
 """
 
 from __future__ import annotations
@@ -106,3 +112,55 @@ def enable(capacity: int = DEFAULT_CAPACITY) -> Tracer:
 def disable() -> None:
     """Restore the shared no-op tracer."""
     _CURRENT[0] = _NULL
+
+
+_ANNOTATION: list = []  # one-slot box: the profiler's annotation class
+
+
+def _annotation():
+    if not _ANNOTATION:  # imported on first use: repro.obs needs no jax
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION.append(TraceAnnotation)
+    return _ANNOTATION[0]
+
+
+class region:
+    """One host span on both clocks::
+
+        with region("sim.build", track="sim-host", jobs=3) as args:
+            ...
+
+    It always enters ``jax.profiler.TraceAnnotation(name)``, with the name
+    exactly and no metadata, since trace readers match names: a profiler
+    trace then holds the span on the profiler's clock, on the calling
+    thread's line, inside whatever annotation encloses the call. While a
+    recording :class:`Tracer` is installed it also appends an ``"X"`` span
+    on ``track`` carrying ``args``. The ``as`` target is ``args`` itself,
+    so values known only at the end (a counter delta) can be added in the
+    block. Parent and request come from nesting: one thread runs one
+    request at a time."""
+
+    __slots__ = ("name", "track", "args", "_ann", "_tr", "_t0")
+
+    def __init__(self, name: str, *, track: str, **args):
+        self.name = name
+        self.track = track
+        self.args = args
+
+    def __enter__(self) -> dict:
+        tr = _CURRENT[0]
+        self._tr = tr if tr.enabled else None
+        self._ann = _annotation()(self.name)
+        self._ann.__enter__()
+        if self._tr is not None:
+            self._t0 = tr.now_wall()
+        return self.args
+
+    def __exit__(self, *exc):
+        tr = self._tr
+        if tr is not None:
+            tr.span(self.name, self._t0, tr.now_wall() - self._t0,
+                    track=self.track, **self.args)
+        self._ann.__exit__(*exc)
+        return False

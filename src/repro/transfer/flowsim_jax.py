@@ -46,13 +46,21 @@ from jax.ops import segment_sum
 from repro.core.plan import MulticastPlan
 from repro.core.topology import GBIT_PER_GB
 from repro.obs.metrics import REGISTRY
-from repro.obs.trace import get_tracer
+from repro.obs.trace import get_tracer, region
 
 from .simconfig import SimConfig
 from .simconfig import resolve as resolve_sim_config
 
 _EPS = 1e-12  # flowsim._EPS
 _INF = float("inf")
+_TRACK = "sim-host"  # the dispatcher's wall spans (the sim track is sim time)
+
+# the dispatcher's work: segments run, scalars of the loop state read back
+# on the host, loop iterations, and those that ran the sequential cascade
+_segments = REGISTRY.counter("sim.segments")
+_host_syncs = REGISTRY.counter("sim.host_syncs")
+_loop_iters = REGISTRY.counter("sim.loop_iters")
+_cascade_seq_iters = REGISTRY.counter("sim.cascade_seq_iters")
 
 
 class _Sc(NamedTuple):
@@ -111,6 +119,7 @@ class _St(NamedTuple):
     now: jnp.ndarray
     it: jnp.ndarray  # loop iterations (the reference's for-range budget)
     events: jnp.ndarray  # iterations that reached the rate step
+    seq: jnp.ndarray  # iterations that ran the sequential cascade
     draining: jnp.ndarray
     stop: jnp.ndarray  # terminal break reached
     t_sched: jnp.ndarray  # next unapplied scripted event time (+inf)
@@ -278,11 +287,21 @@ def _step(st: _St, cn: _Cn, sc: _Sc) -> _St:
     events = st.events + work.astype(i64)
 
     changed = work & (~st.rates_valid | jnp.any(active != st.last_active))
-    rates = jax.lax.cond(
-        changed,
-        lambda: _compute_rates(st, cn, sc, active),
-        lambda: st.rates,
-    )
+    with jax.named_scope("rate_solve"):
+        rates = jax.lax.cond(
+            changed,
+            lambda: _compute_rates(st, cn, sc, active),
+            lambda: st.rates,
+        )
+    with jax.named_scope("state_update"):
+        return _advance(st, cn, sc, active, work, jump, events, rates)
+
+
+def _advance(st: _St, cn: _Cn, sc: _Sc, active, work, jump, events,
+             rates) -> _St:
+    """The rest of ``_step`` once the rates are known: stall check, fluid
+    step, event-less jump, hop completions and enqueues."""
+    i64 = st.q_head.dtype
     last_active = jnp.where(work, active, st.last_active)
     rates_valid = st.rates_valid | work
     t_next = jnp.where(st.draining, _INF, st.t_sched)
@@ -414,20 +433,23 @@ def _segment(st: _St, cn: _Cn, sc: _Sc) -> _St:
         )
         run = ~st.stop & ~st.draining
         use_seq = jnp.any(st.relay_occ[: sc.ns] >= cn.relay_cap)
-        st = _cascade_batch(st, cn, sc, run & ~use_seq)
+        with jax.named_scope("cascade_batch"):
+            st = _cascade_batch(st, cn, sc, run & ~use_seq)
         # The per-chunk sequential cascade (relay caps binding) is rare and
         # inherently serial; it stays behind a cond, but only the four small
         # arrays it writes are carried — the big buffers are closure-read.
         small = (st.chunk_arr, st.remaining, st.q_head, st.relay_occ)
-        small = jax.lax.cond(
-            run & use_seq,
-            lambda t: _cascade_seq(t, st, cn, sc),
-            lambda t: t,
-            small,
-        )
+        with jax.named_scope("cascade_seq"):
+            small = jax.lax.cond(
+                run & use_seq,
+                lambda t: _cascade_seq(t, st, cn, sc),
+                lambda t: t,
+                small,
+            )
         st = st._replace(
             chunk_arr=small[0], remaining=small[1],
             q_head=small[2], relay_occ=small[3],
+            seq=st.seq + (run & use_seq).astype(st.seq.dtype),
         )
         return _step(st, cn, sc)
 
@@ -435,6 +457,12 @@ def _segment(st: _St, cn: _Cn, sc: _Sc) -> _St:
 
 
 # ------------------------------------------------------------------ host side
+def _pull(cast, x):
+    """One scalar of the loop state read back on the host: a sync."""
+    _host_syncs.inc()
+    return cast(x)
+
+
 def _build(su, cfg, sched, solver: str):
     """Materialized scenario -> (static key, constants, initial state)."""
     from repro.kernels.waterfill.waterfill import BIG
@@ -533,6 +561,7 @@ def _build(su, cfg, sched, solver: str):
     )
     st = _St(
         now=jnp.float64(0.0), it=jnp.int64(0), events=jnp.int64(0),
+        seq=jnp.int64(0),
         draining=jnp.bool_(False), stop=jnp.bool_(False),
         t_sched=jnp.float64(sched[0][0] if sched else _INF),
         chunk_arr=jnp.full(ncp, -1, dtype=jnp.int64),
@@ -567,7 +596,7 @@ def _host_apply_due(st: _St, su, sched, ptr, vm_alive, retried, use_edge,
     (including its Skytrace instants). Returns (new state, new ptr)."""
     from .events import RATE_EVENTS, T_EPS, VMFailure
 
-    now = float(st.now)
+    now = _pull(float, st.now)
     # np.array (copy): np.asarray of a jax array can be a read-only view
     h = {
         "chunk_arr": np.array(st.chunk_arr), "remaining":
@@ -676,16 +705,17 @@ def _finalize(st: _St, su, jobs, cfg, retried, tr):
 
     top = su.top
     ne = len(su.edges_used)
-    now = float(st.now)
     nc = su.conn_job.shape[0]
-    chunk_arr = np.asarray(st.chunk_arr)[:nc]
-    arrived = np.asarray(st.arrived)
-    finished = np.asarray(st.finished)
-    finish_t = np.asarray(st.finish)
-    delivered = np.asarray(st.delivered)
-    job_edge_gbit = np.asarray(st.jeg)
-    job_edge_obs_gbit = np.asarray(st.jeo)
-    job_edge_busy = np.asarray(st.jeb)
+    # one transfer of everything the accounting reads
+    (now, events, it, seq, chunk_arr, arrived, finished, finish_t,
+     delivered, job_edge_gbit, job_edge_obs_gbit, job_edge_busy) = (
+        jax.device_get((st.now, st.events, st.it, st.seq, st.chunk_arr,
+                        st.arrived, st.finished, st.finish, st.delivered,
+                        st.jeg, st.jeo, st.jeb)))
+    now = float(now)
+    chunk_arr = chunk_arr[:nc]
+    _loop_iters.inc(int(it))
+    _cascade_seq_iters.inc(int(seq))
     horizon_s = cfg.horizon_s
 
     horizon_cut = horizon_s is not None and now >= horizon_s - T_EPS
@@ -750,7 +780,7 @@ def _finalize(st: _St, su, jobs, cfg, retried, tr):
     if tr.enabled:
         tr.instant("sim.end", now,
                    delivered=sum(int(r.chunks_delivered) for r in out))
-    return MultiSimResult(jobs=out, time_s=now, events=int(st.events))
+    return MultiSimResult(jobs=out, time_s=now, events=int(events))
 
 
 def _rate_solver_for(platform: str, nc: int, nv: int, ne: int) -> str:
@@ -802,49 +832,57 @@ def simulate_multi_jax(
     )
     if _rate_solver not in ("auto", "masked", "pallas"):
         raise ValueError(f"unknown rate solver {_rate_solver!r}")
-    su = materialize_jobs(
-        jobs, seed=cfg.seed, straggler_prob=cfg.straggler_prob,
-        straggler_speed=cfg.straggler_speed, exec_top=cfg.exec_top,
-    )
-    solver = _rate_solver
-    if solver == "auto":
-        solver = _rate_solver_for(
-            jax.default_backend(), int(su.conn_job.shape[0]),
-            int(su.vm_eg_cap.shape[0]), len(su.edges_used),
-        )
-    # runs per rate solver: which one the size rule picked
-    REGISTRY.counter(f"sim.rate_solver.{solver}").inc()
-    sched = sorted_schedule(jobs, faults)
-    tr = get_tracer()
-    if tr.enabled:
-        tr.instant("sim.start", 0.0, jobs=len(jobs), scheduled=len(sched))
-    retried = np.zeros(len(jobs), dtype=np.int64)
-    vm_alive = np.ones(su.vm_eg_cap.shape[0], dtype=bool)
-    with jax.enable_x64(True):
-        sc, cn, st = _build(su, cfg, sched, solver)
-        ptr = 0
-        max_events = int(cn.max_events)
-        while True:
-            if not bool(st.draining):
-                st, ptr = _host_apply_due(
-                    st, su, sched, ptr, vm_alive, retried,
-                    cfg.link_capacity_scale is not None, sc.qcap, tr,
-                )
-            st = _segment(st, cn, sc)
-            n_td = int(st.td_n)
-            if n_td and tr.enabled:
-                td_time = np.asarray(st.td_time)
-                td_job = np.asarray(st.td_job)
-                for i in range(n_td):
-                    tr.instant("sim.job_done", float(td_time[i]),
-                               job=int(td_job[i]))
-            if n_td:
-                st = st._replace(td_n=jnp.int64(0))
-            if bool(st.stop) or int(st.it) >= max_events:
-                break
-            due = not bool(st.draining) and ptr < len(sched) and (
-                sched[ptr][0] <= float(st.now) + T_EPS
+    with region("sim.run", track=_TRACK, jobs=len(jobs)):
+        with region("sim.materialize", track=_TRACK):
+            su = materialize_jobs(
+                jobs, seed=cfg.seed, straggler_prob=cfg.straggler_prob,
+                straggler_speed=cfg.straggler_speed, exec_top=cfg.exec_top,
             )
-            if not due:
-                break
-        return _finalize(st, su, jobs, cfg, retried, tr)
+            sched = sorted_schedule(jobs, faults)
+        solver = _rate_solver
+        if solver == "auto":
+            solver = _rate_solver_for(
+                jax.default_backend(), int(su.conn_job.shape[0]),
+                int(su.vm_eg_cap.shape[0]), len(su.edges_used),
+            )
+        # runs per rate solver: which one the size rule picked
+        REGISTRY.counter(f"sim.rate_solver.{solver}").inc()
+        tr = get_tracer()
+        if tr.enabled:
+            tr.instant("sim.start", 0.0, jobs=len(jobs),
+                       scheduled=len(sched))
+        retried = np.zeros(len(jobs), dtype=np.int64)
+        vm_alive = np.ones(su.vm_eg_cap.shape[0], dtype=bool)
+        with jax.enable_x64(True):
+            with region("sim.build", track=_TRACK):
+                sc, cn, st = _build(su, cfg, sched, solver)
+            ptr = 0
+            max_events = int(cn.max_events)
+            while True:
+                if not _pull(bool, st.draining):
+                    with region("sim.apply_due", track=_TRACK):
+                        st, ptr = _host_apply_due(
+                            st, su, sched, ptr, vm_alive, retried,
+                            cfg.link_capacity_scale is not None, sc.qcap, tr,
+                        )
+                _segments.inc()
+                with region("sim.segment", track=_TRACK):
+                    st = _segment(st, cn, sc)
+                    n_td = _pull(int, st.td_n)
+                if n_td and tr.enabled:
+                    td_time = np.asarray(st.td_time)
+                    td_job = np.asarray(st.td_job)
+                    for i in range(n_td):
+                        tr.instant("sim.job_done", float(td_time[i]),
+                                   job=int(td_job[i]))
+                if n_td:
+                    st = st._replace(td_n=jnp.int64(0))
+                if _pull(bool, st.stop) or _pull(int, st.it) >= max_events:
+                    break
+                due = not _pull(bool, st.draining) and ptr < len(sched) and (
+                    sched[ptr][0] <= _pull(float, st.now) + T_EPS
+                )
+                if not due:
+                    break
+            with region("sim.finalize", track=_TRACK):
+                return _finalize(st, su, jobs, cfg, retried, tr)

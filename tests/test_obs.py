@@ -20,6 +20,7 @@ from repro.obs import (
     enable,
     get_registry,
     get_tracer,
+    region,
     text_timeline,
     to_chrome_trace,
     trace_json,
@@ -115,6 +116,48 @@ def test_enable_installs_and_disable_restores():
     assert get_tracer() is tr and tr.enabled
     disable()
     assert get_tracer().enabled is False
+
+
+def test_region_records_a_span_only_while_a_tracer_records():
+    with region("planner.x", track="planner", n=1) as args:
+        args["m"] = 2
+    tr = enable()
+    with region("planner.x", track="planner", n=1) as args:
+        args["m"] = 2  # a value known only at the end
+    (ev,) = tr.events()
+    assert ev[:2] == ("X", "planner.x") and ev[4] == "planner"
+    assert ev[5] == {"n": 1, "m": 2}
+    assert 0.0 <= ev[2] and 0.0 <= ev[3]
+    disable()
+    with region("planner.x", track="planner"):
+        pass
+    assert len(tr) == 1
+
+
+def test_region_records_its_span_when_the_block_raises():
+    tr = enable()
+    with pytest.raises(ValueError):
+        with region("sim.build", track="sim-host"):
+            raise ValueError("boom")
+    assert [e[1] for e in tr.events()] == ["sim.build"]
+
+
+def test_planner_spans_keep_their_names_and_args():
+    from repro.core import Planner, PlanSpec, toy_topology
+
+    top = toy_topology(n=6, seed=4)
+    planner = Planner(top)
+    src, dst = top.regions[0].key, top.regions[1].key
+    tr = enable()
+    planner.plan(PlanSpec(objective="max_throughput", src=src, dst=dst))
+    planner.plan_cohort([PlanSpec(objective="cost_min", src=src, dst=dst,
+                                  tput_goal_gbps=0.5, volume_gb=1.0)])
+    spans = {e[1]: e for e in tr.events() if e[4] == "planner"}
+    assert set(spans["planner.plan"][5]) == {
+        "objective", "src", "dst", "struct_builds"}
+    assert spans["planner.plan_cohort"][5] == {
+        "n_specs": 1, "n_batched_routes": 1}
+    assert {f"bnb.stage{k}" for k in range(5)} <= set(spans)
 
 
 # ----------------------------------------------------------------- export

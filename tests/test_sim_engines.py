@@ -15,8 +15,10 @@ what the engines actually share:
     object-per-connection oracle, so they are pinned to float tolerance;
     ``per_edge_active_s``/``per_edge_obs_gb`` are vectorized-only
     telemetry (documented on ``JobSimResult``) and excluded.
-  * Skytrace — the emitted streams must be identical tuples across all
-    three engines: the observability plane cannot depend on the engine.
+  * Skytrace — the emitted sim-time streams must be identical tuples
+    across all three engines: the observability plane cannot depend on
+    the engine. The jax engine's dispatcher adds its own wall spans, on
+    the ``sim-host`` track.
 """
 
 from __future__ import annotations
@@ -102,9 +104,16 @@ def assert_parity(out, traces):
             assert ega[e] == pytest.approx(egb[e], rel=1e-9)
         assert da == db
 
-    # the Skytrace stream is engine-independent, tuple for tuple
+    # the sim-time Skytrace stream is engine-independent, tuple for tuple;
+    # the jax engine adds its dispatcher's wall spans on a track of their own
     assert traces["soa"] == traces["ref"]
-    assert traces["jax"] == traces["ref"]
+    assert [e for e in traces["jax"] if e[4] == "sim"] == traces["ref"]
+    host = [e for e in traces["jax"] if e[4] != "sim"]
+    assert {(e[0], e[4]) for e in host} == {("X", "sim-host")}
+    assert {e[1] for e in host} == {
+        "sim.run", "sim.materialize", "sim.build", "sim.apply_due",
+        "sim.segment", "sim.finalize",
+    }
 
 
 def test_plain_three_jobs(top):
