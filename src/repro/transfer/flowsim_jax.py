@@ -19,11 +19,14 @@ Exact-semantics notes (each is load-bearing for chunk-for-chunk parity):
     comparison the SoA loop makes (``now >= horizon - T_EPS``,
     ``t_next < horizon``, ``now + dt > t_next``, the stall check's
     ``t_next is None``) evaluates identically under IEEE inf;
-  * cascade refills run a single batched pass when no relay buffer is at
-    capacity (blocked-ness is per stage and ``relay_occ`` only decreases
-    during a cascade, so eligibility is static and the SoA pass order
-    equals rank-in-stage order); with any buffer full it falls back to an
-    exact sequential sweep replicating the reference pass structure;
+  * cascade refills are stage-batched passes: blocked-ness is per stage
+    (a child buffer at capacity), ``relay_occ`` only decreases during a
+    cascade and nothing is pushed, so every unblocked stage takes
+    min(idle lanes, queue) chunks FIFO in ascending-lane order once, and
+    stages unblock monotonically up the stage DAG. With no buffer at
+    capacity that is a single pass; with one full, passes that read
+    blocked-ness at their start repeat until one takes nothing — at most
+    one per DAG level plus one — and reach the reference's fixed point;
   * ``moved = rates * dt`` feeds both the remaining-update and the
     telemetry segment-sums — the multiple use (plus living inside
     ``lax.while_loop``) keeps LLVM from contracting the multiply-subtract
@@ -56,11 +59,13 @@ _INF = float("inf")
 _TRACK = "sim-host"  # the dispatcher's wall spans (the sim track is sim time)
 
 # the dispatcher's work: segments run, scalars of the loop state read back
-# on the host, loop iterations, and those that ran the sequential cascade
+# on the host, loop iterations, those that ran the multi-pass cascade, and
+# that cascade's passes
 _segments = REGISTRY.counter("sim.segments")
 _host_syncs = REGISTRY.counter("sim.host_syncs")
 _loop_iters = REGISTRY.counter("sim.loop_iters")
 _cascade_seq_iters = REGISTRY.counter("sim.cascade_seq_iters")
+_cascade_seq_passes = REGISTRY.counter("sim.cascade_seq_passes")
 
 
 class _Sc(NamedTuple):
@@ -119,7 +124,8 @@ class _St(NamedTuple):
     now: jnp.ndarray
     it: jnp.ndarray  # loop iterations (the reference's for-range budget)
     events: jnp.ndarray  # iterations that reached the rate step
-    seq: jnp.ndarray  # iterations that ran the sequential cascade
+    seq: jnp.ndarray  # iterations with a relay buffer at capacity
+    seq_passes: jnp.ndarray  # stage-batched passes those iterations ran
     draining: jnp.ndarray
     stop: jnp.ndarray  # terminal break reached
     t_sched: jnp.ndarray  # next unapplied scripted event time (+inf)
@@ -177,93 +183,75 @@ def _compute_rates(st: _St, cn: _Cn, sc: _Sc, active):
     )
 
 
-def _cascade_batch(st: _St, cn: _Cn, sc: _Sc, run) -> _St:
-    """Single-pass batched refill — exact while no relay buffer is full.
+def _cascade_batch(small, st: _St, cn: _Cn, sc: _Sc, run, blocked=None):
+    """One batched refill pass over ``small`` = (chunk_arr, remaining,
+    q_head, relay_occ); returns the new four and the lanes that took.
 
+    Every idle lane of a stage not in ``blocked`` ([NS + 1] bool; None
+    blocks no stage, which is exact while no relay buffer is full) takes
+    the next chunk of its stage's queue in ascending-lane (FIFO) order,
+    while the queue lasts. The ring buffers are read through ``st``.
     ``run`` predicates the whole pass (False turns every take off): the
     hot loop calls this unconditionally instead of under ``lax.cond``,
     because a cond whose branches carry the state would make XLA copy the
     O(chunks) ring buffers/bitmaps every iteration (see ``_step``)."""
-    i64 = st.q_head.dtype
+    chunk_arr, remaining, q_head, relay_occ = small
+    i64 = q_head.dtype
     idle = (
-        run & (st.chunk_arr < 0) & st.conn_alive
+        run & (chunk_arr < 0) & st.conn_alive
         & st.arrived[cn.conn_job] & cn.conn_valid
     )
-    qlen = st.q_tail - st.q_head
+    qlen = st.q_tail - q_head
     elig = idle & (qlen[cn.conn_sid] > 0)
+    if blocked is not None:
+        elig = elig & ~blocked[cn.conn_sid]
     ef = elig.astype(i64)
     excl = jnp.cumsum(ef) - ef
     rank = excl - excl[cn.conn_first]
     take = elig & (rank < qlen[cn.conn_sid])
     row = jnp.where(take, cn.conn_sid, sc.ns)
-    pos = (st.q_head[row] + rank) % sc.qcap
+    pos = (q_head[row] + rank) % sc.qcap
     ch = st.ready_buf[row, jnp.where(take, pos, 0)]
     cnt = segment_sum(take.astype(i64), row, num_segments=sc.ns + 1)
-    return st._replace(
-        chunk_arr=jnp.where(take, ch, st.chunk_arr),
-        remaining=jnp.where(take, cn.chunk_size, st.remaining),
-        q_head=st.q_head + cnt,
-        relay_occ=st.relay_occ - jnp.where(cn.stage_hop > 0, cnt, 0),
-    )
+    return (
+        jnp.where(take, ch, chunk_arr),
+        jnp.where(take, cn.chunk_size, remaining),
+        q_head + cnt,
+        relay_occ - jnp.where(cn.stage_hop > 0, cnt, 0),
+    ), take
 
 
 def _cascade_seq(small, st: _St, cn: _Cn, sc: _Sc):
-    """Exact sequential replication of the reference cascade passes.
+    """The reference cascade with relay buffers binding, as stage-batched
+    passes; returns the new ``small`` and the number of passes run.
 
-    Carries only the four arrays the cascade writes (``small`` =
-    (chunk_arr, remaining, q_head, relay_occ)); everything else — the
-    ready ring buffers in particular — is read through ``st`` as a
-    read-only closure capture, so the enclosing ``lax.cond`` never has
-    the big buffers among its outputs (no per-iteration copies)."""
-    i64 = st.q_head.dtype
+    Each pass blocks the stages with a child buffer at capacity (read at
+    the pass start) and lets every other stage take (``_cascade_batch``),
+    until a pass takes nothing. This reaches the reference's fixed point:
+    a stage's blocked-ness depends only on its children's ``relay_occ``,
+    which only falls during a cascade and nothing is pushed, so an
+    unblocked stage takes min(idle lanes, queue) FIFO once and the
+    stages unblock monotonically up the stage DAG — at most one taking
+    pass per level. Carries only the four small arrays (``small`` =
+    (chunk_arr, remaining, q_head, relay_occ)); the ready ring buffers
+    are read through ``st`` as a closure capture, so the enclosing
+    ``lax.cond`` never has the big buffers among its outputs."""
+    kids = jnp.maximum(cn.children, 0)
 
     def pass_body(carry):
-        (chunk_arr, remaining, q_head, relay_occ), _ = carry
-        idle = (
-            (chunk_arr < 0) & st.conn_alive
-            & st.arrived[cn.conn_job] & cn.conn_valid
+        small, _, passes = carry
+        relay_occ = small[3]
+        blocked = jnp.any(
+            (cn.children >= 0) & (relay_occ[kids] >= cn.relay_cap), axis=1
         )
-        any_idle = jnp.any(idle)
-        cand = idle & ((st.q_tail - q_head)[cn.conn_sid] > 0)
+        small, take = _cascade_batch(small, st, cn, sc, True, blocked)
+        return small, jnp.any(take), passes + 1
 
-        def per_conn(i, inner):
-            (chunk_arr, remaining, q_head, relay_occ), prog = inner
-            sid = cn.conn_sid[i]
-            want = cand[i] & (chunk_arr[i] < 0)
-            kids = cn.children[sid]
-            blocked = jnp.any(
-                (kids >= 0)
-                & (relay_occ[jnp.maximum(kids, 0)] >= cn.relay_cap)
-            )
-            take = want & ~blocked & (st.q_tail[sid] > q_head[sid])
-            ch = st.ready_buf[sid, q_head[sid] % sc.qcap]
-            one = jnp.where(take, jnp.asarray(1, i64), jnp.asarray(0, i64))
-            dec = jnp.where(cn.stage_hop[sid] > 0, one, jnp.asarray(0, i64))
-            out = (
-                chunk_arr.at[i].set(jnp.where(take, ch, chunk_arr[i])),
-                remaining.at[i].set(
-                    jnp.where(take, cn.chunk_size[i], remaining[i])
-                ),
-                q_head.at[sid].add(one),
-                relay_occ.at[sid].add(-dec),
-            )
-            return out, prog | take
-
-        def do_pass(t):
-            return jax.lax.fori_loop(
-                0, sc.ncp, per_conn, (t, jnp.bool_(False))
-            )
-
-        t, prog = jax.lax.cond(
-            any_idle, do_pass, lambda t: (t, jnp.bool_(False)),
-            (chunk_arr, remaining, q_head, relay_occ),
-        )
-        return t, prog
-
-    small, _ = jax.lax.while_loop(
-        lambda c: c[1], pass_body, (small, jnp.bool_(True))
+    small, _, passes = jax.lax.while_loop(
+        lambda c: c[1], pass_body,
+        (small, jnp.bool_(True), jnp.zeros((), st.seq.dtype)),
     )
-    return small
+    return small, passes
 
 
 def _step(st: _St, cn: _Cn, sc: _Sc) -> _St:
@@ -277,7 +265,7 @@ def _step(st: _St, cn: _Cn, sc: _Sc) -> _St:
     buffers and dedup bitmaps on EVERY loop iteration — measured ~14 MB
     per event at 1e5 chunks, which is what made the device loop lose to
     the numpy engine. Only ``_compute_rates`` (padded-lane output) and
-    the rare full-relay sequential cascade stay behind conds, and neither
+    the rare full-relay multi-pass cascade stay behind conds, and neither
     carries a chunk-sized output."""
     i64 = st.q_head.dtype
     active = st.chunk_arr >= 0
@@ -433,23 +421,24 @@ def _segment(st: _St, cn: _Cn, sc: _Sc) -> _St:
         )
         run = ~st.stop & ~st.draining
         use_seq = jnp.any(st.relay_occ[: sc.ns] >= cn.relay_cap)
-        with jax.named_scope("cascade_batch"):
-            st = _cascade_batch(st, cn, sc, run & ~use_seq)
-        # The per-chunk sequential cascade (relay caps binding) is rare and
-        # inherently serial; it stays behind a cond, but only the four small
-        # arrays it writes are carried — the big buffers are closure-read.
         small = (st.chunk_arr, st.remaining, st.q_head, st.relay_occ)
+        with jax.named_scope("cascade_batch"):
+            small, _ = _cascade_batch(small, st, cn, sc, run & ~use_seq)
+        # The multi-pass cascade (relay caps binding) is rare; it stays
+        # behind a cond, but only the four small arrays it writes are
+        # carried — the big buffers are closure-read.
         with jax.named_scope("cascade_seq"):
-            small = jax.lax.cond(
+            small, passes = jax.lax.cond(
                 run & use_seq,
                 lambda t: _cascade_seq(t, st, cn, sc),
-                lambda t: t,
+                lambda t: (t, jnp.zeros((), st.seq.dtype)),
                 small,
             )
         st = st._replace(
             chunk_arr=small[0], remaining=small[1],
             q_head=small[2], relay_occ=small[3],
             seq=st.seq + (run & use_seq).astype(st.seq.dtype),
+            seq_passes=st.seq_passes + passes,
         )
         return _step(st, cn, sc)
 
@@ -561,7 +550,7 @@ def _build(su, cfg, sched, solver: str):
     )
     st = _St(
         now=jnp.float64(0.0), it=jnp.int64(0), events=jnp.int64(0),
-        seq=jnp.int64(0),
+        seq=jnp.int64(0), seq_passes=jnp.int64(0),
         draining=jnp.bool_(False), stop=jnp.bool_(False),
         t_sched=jnp.float64(sched[0][0] if sched else _INF),
         chunk_arr=jnp.full(ncp, -1, dtype=jnp.int64),
@@ -707,15 +696,17 @@ def _finalize(st: _St, su, jobs, cfg, retried, tr):
     ne = len(su.edges_used)
     nc = su.conn_job.shape[0]
     # one transfer of everything the accounting reads
-    (now, events, it, seq, chunk_arr, arrived, finished, finish_t,
-     delivered, job_edge_gbit, job_edge_obs_gbit, job_edge_busy) = (
-        jax.device_get((st.now, st.events, st.it, st.seq, st.chunk_arr,
-                        st.arrived, st.finished, st.finish, st.delivered,
-                        st.jeg, st.jeo, st.jeb)))
+    (now, events, it, seq, seq_passes, chunk_arr, arrived, finished,
+     finish_t, delivered, job_edge_gbit, job_edge_obs_gbit,
+     job_edge_busy) = jax.device_get((
+         st.now, st.events, st.it, st.seq, st.seq_passes, st.chunk_arr,
+         st.arrived, st.finished, st.finish, st.delivered, st.jeg, st.jeo,
+         st.jeb))
     now = float(now)
     chunk_arr = chunk_arr[:nc]
     _loop_iters.inc(int(it))
     _cascade_seq_iters.inc(int(seq))
+    _cascade_seq_passes.inc(int(seq_passes))
     horizon_s = cfg.horizon_s
 
     horizon_cut = horizon_s is not None and now >= horizon_s - T_EPS

@@ -19,15 +19,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import default_topology, direct_plan, milp, toy_topology
+from repro.core import (
+    Planner,
+    PlanSpec,
+    default_topology,
+    direct_plan,
+    milp,
+    toy_topology,
+)
 from repro.core.ron import ron_plan
 from repro.core.solver import ipm_jax
 from repro.core.solver.ipm import solve_lp
 from repro.obs.metrics import REGISTRY
 from repro.transfer import TransferJob, VMFailure, flowsim_jax, simulate
+from repro.transfer.events import materialize_jobs
 
 SRC, DST = "aws:us-west-2", "aws:eu-central-1"
 RELAY_SRC, RELAY_DST = "azure:canadacentral", "gcp:asia-northeast1"
+MC_SRC = "gcp:us-central1"
+MC_DSTS = ("gcp:europe-west1", "gcp:europe-west3", "gcp:europe-west4")
 SIM_SPANS = ("sim.run", "sim.materialize", "sim.build", "sim.apply_due",
              "sim.segment", "sim.finalize")
 IPM_SPANS = ("ipm.pack", "ipm.device_call", "ipm.certify")
@@ -91,19 +101,211 @@ def test_sim_counters_count_the_dispatch_loop(top, monkeypatch):
     assert _value("sim.cascade_seq_iters") == 0  # no relay
 
 
-@pytest.mark.parametrize("cap,seq", [(1, True), (64, False)])
-def test_sequential_cascade_iterations_are_counted(top, cap, seq):
-    """A relay buffer of one chunk fills: the loop takes the sequential
-    cascade, and counts the iterations that did; at the default buffer
-    it never fills here."""
-    jobs = [TransferJob(ron_plan(top, RELAY_SRC, RELAY_DST, 0.25,
-                                 num_vms=1), "relayed")]
+@pytest.fixture(scope="module")
+def multicast_plan(top):
+    """A cost_min replication plan whose stage DAG fans out."""
+    planner = Planner(top, max_relays=6)
+    return planner.plan(PlanSpec(
+        objective="cost_min", src=MC_SRC, dsts=MC_DSTS,
+        tput_goal_gbps=2.0, volume_gb=1.0,
+    ))
+
+
+@pytest.mark.parametrize("kind,volume,cap,seq", [
+    pytest.param("overlay", 0.25, 1, True, id="1-True"),
+    pytest.param("overlay", 0.25, 64, False, id="64-False"),
+    pytest.param("overlay", 2.0, 2, True, id="2-True"),
+    pytest.param("multicast", 1.0, 1, True, id="multicast-1-True"),
+])
+def test_sequential_cascade_iterations_are_counted(top, multicast_plan,
+                                                   kind, volume, cap, seq):
+    """A small relay buffer fills: the loop takes the multi-pass cascade,
+    counts the iterations that did and their passes (at least one each,
+    at most one per level of the stage DAG plus one), and stays bitwise
+    equal to the numpy engine; at the default buffer it never fills
+    here."""
+    if kind == "overlay":
+        plan = ron_plan(top, RELAY_SRC, RELAY_DST, volume, num_vms=1)
+    else:
+        plan = multicast_plan
+        assert plan.volume_gb == volume
+    jobs = [TransferJob(plan, "relayed")]
+    su = materialize_jobs(jobs, seed=0)
+    assert su.max_hops >= 2
+    if kind == "multicast":
+        assert max(len(c) for c in su.stage_children) >= 2  # fan-out
     soa = simulate(jobs, engine="soa", seed=0, relay_buffer_chunks=cap)
     got = simulate(jobs, engine="jax", seed=0, relay_buffer_chunks=cap)
     _same(got, soa)
     n_seq = _value("sim.cascade_seq_iters")
+    passes = _value("sim.cascade_seq_passes")
     assert (n_seq > 0) is seq
     assert n_seq <= _value("sim.loop_iters")
+    assert n_seq <= passes <= (su.max_hops + 1) * n_seq
+
+
+def _reference_cascade(chunk_arr, remaining, q_head, relay_occ, *, q_tail,
+                       ready_buf, conn_sid, children, stage_hop,
+                       chunk_size, usable, cap):
+    """``flowsim``'s cascade: passes of ``try_refill`` over the idle lanes
+    with queued work, in ascending lane order, until one takes nothing."""
+    chunk_arr, remaining = chunk_arr.copy(), remaining.copy()
+    q_head, relay_occ = q_head.copy(), relay_occ.copy()
+    qcap = ready_buf.shape[1]
+    passes = 0
+    while True:
+        passes += 1
+        idle = (chunk_arr < 0) & usable
+        if not idle.any():
+            break
+        queue_work = (q_tail - q_head)[conn_sid] > 0
+        progressed = False
+        for ci in np.flatnonzero(idle & queue_work):
+            sid = conn_sid[ci]
+            if any(relay_occ[k] >= cap for k in children[sid] if k >= 0):
+                continue
+            if q_tail[sid] == q_head[sid]:
+                continue
+            chunk_arr[ci] = ready_buf[sid, q_head[sid] % qcap]
+            q_head[sid] += 1
+            remaining[ci] = chunk_size[ci]
+            if stage_hop[sid] > 0:
+                relay_occ[sid] -= 1
+            progressed = True
+        if not progressed:
+            break
+    return (chunk_arr, remaining, q_head, relay_occ), passes
+
+
+# Stage DAGs over the four stages of two overlay jobs: children, hop of each
+# stage, and per stage (idle lanes, queued chunks); a relay stage's buffer
+# holds its queue. Each child drains below the cap only by its own takes.
+_DAGS = {
+    # the parent's lanes come first: the reference unblocks one level a pass
+    "child_sid_above_parent": ({0: [1], 1: [2]}, [0, 1, 2, 0], 3,
+                               [(4, 5), (2, 3), (2, 4), (0, 0)]),
+    # the child's lanes come first: the reference unblocks in one pass
+    "child_sid_below_parent": ({3: [2], 2: [1]}, [0, 2, 1, 0], 3,
+                               [(0, 0), (2, 4), (2, 3), (4, 5)]),
+    # fan-out: one child drains below the cap, the other stays at it
+    "fan_out_one_full": ({0: [1, 2]}, [0, 1, 1, 0], 2,
+                         [(3, 6), (1, 2), (1, 3), (0, 0)]),
+    "fan_out_both_drain": ({0: [1, 2]}, [0, 1, 1, 0], 2,
+                           [(3, 6), (1, 2), (2, 3), (0, 0)]),
+}
+
+
+@pytest.mark.parametrize("dag", sorted(_DAGS))
+def test_cascade_passes_reach_the_reference_fixed_point(top, dag):
+    """``_cascade_seq`` alone against a transliteration of the reference's
+    pass loop, on states where a child stage unblocks its parent only
+    through its own takes."""
+    from repro.transfer.events import sorted_schedule
+    from repro.transfer.simconfig import SimConfig
+
+    kids, hops, cap, per_stage = _DAGS[dag]
+    jobs = [TransferJob(ron_plan(top, RELAY_SRC, RELAY_DST, 0.25,
+                                 num_vms=1), n) for n in ("a", "b")]
+    su = materialize_jobs(jobs, seed=0)
+    assert su.n_stages == 4
+    rng = np.random.default_rng(7)
+    with jax.enable_x64(True):
+        sc, cn, st = flowsim_jax._build(
+            su, SimConfig(), sorted_schedule(jobs, ()), "masked")
+        ns, ncp, qcap = sc.ns, sc.ncp, sc.qcap
+        children = np.full((ns + 1, 2), -1, dtype=np.int64)
+        for s, ks in kids.items():
+            children[s, : len(ks)] = ks
+        stage_hop = np.zeros(ns + 1, dtype=np.int64)
+        stage_hop[:ns] = hops
+        conn_sid = np.asarray(cn.conn_sid)
+        valid = np.asarray(cn.conn_valid)
+        chunk_arr = np.zeros(ncp, dtype=np.int64)  # busy lanes
+        alive = valid.copy()
+        q_head = np.zeros(ns + 1, dtype=np.int64)
+        q_tail = np.zeros(ns + 1, dtype=np.int64)
+        relay_occ = np.zeros(ns + 1, dtype=np.int64)
+        ready_buf = np.zeros((ns + 1, qcap), dtype=np.int64)
+        for s, (n_idle, n_queued) in enumerate(per_stage):
+            lanes = rng.permutation(np.flatnonzero(valid & (conn_sid == s)))
+            chunk_arr[lanes[: n_idle + 1]] = -1
+            alive[lanes[n_idle]] = False  # idle but dead: never takes
+            q_head[s] = qcap - 2  # the queue wraps round the ring
+            q_tail[s] = q_head[s] + n_queued
+            pos = (q_head[s] + np.arange(n_queued)) % qcap
+            ready_buf[s, pos] = rng.permutation(qcap)[:n_queued]
+            relay_occ[s] = n_queued if hops[s] > 0 else 0
+        remaining = np.where(chunk_arr >= 0, 0.5, 0.0)
+        cn = cn._replace(children=jnp.asarray(children),
+                         stage_hop=jnp.asarray(stage_hop),
+                         relay_cap=jnp.int64(cap))
+        st = st._replace(
+            arrived=jnp.ones_like(st.arrived),
+            conn_alive=jnp.asarray(alive), q_tail=jnp.asarray(q_tail),
+            ready_buf=jnp.asarray(ready_buf))
+        small = tuple(jnp.asarray(a)
+                      for a in (chunk_arr, remaining, q_head, relay_occ))
+        got, passes = jax.jit(flowsim_jax._cascade_seq, static_argnums=3)(
+            small, st, cn, sc)
+        got = jax.device_get(got)
+    want, ref_passes = _reference_cascade(
+        chunk_arr, remaining, q_head, relay_occ, q_tail=q_tail,
+        ready_buf=ready_buf, conn_sid=conn_sid, children=children,
+        stage_hop=stage_hop, chunk_size=np.asarray(cn.chunk_size),
+        usable=alive, cap=cap)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert ref_passes >= 2
+    assert (want[2] > q_head).any()  # some stage took
+    assert 2 <= int(passes) <= _levels(kids) + 1
+
+
+def _levels(kids):
+    """Stages on the longest chain of the DAG ``kids``."""
+    def down(s):
+        return 1 + max((down(k) for k in kids.get(s, ())), default=0)
+
+    return max(down(s) for s in kids)
+
+
+def test_vm_kill_at_a_full_relay_buffer_matches_soa(top, monkeypatch):
+    """A relay VM dies while its stage's buffer is at the cap, under the
+    overlay benchmark's seeded chaos (one gray and one flapping link on
+    the plan's links): the jax engine stays bitwise equal to the numpy
+    engine."""
+    from repro.transfer import ChaosScenario
+
+    cap = 2
+    plan = ron_plan(top, RELAY_SRC, RELAY_DST, 1.0, num_vms=2)
+    jobs = [TransferJob(plan, "relayed", 0.0, 4.0)]
+    relay = next(int(r) for r in np.flatnonzero(plan.N)
+                 if r not in (plan.src, plan.dst))
+    seed = int(np.random.default_rng([3_000_000_123, 1]).integers(2**62))
+    links = sorted({tuple(e) for e in np.argwhere(plan.F > 0).tolist()})
+    faults = ChaosScenario(top, seed=seed, links=links, horizon_s=10.0,
+                           n_gray=1, n_flapping=1, n_brownouts=0,
+                           n_region_outages=0).events(len(jobs))
+    faults.append(VMFailure(t_s=0.5, job=0, region=relay, count=1))
+
+    at_kill = []
+    real = flowsim_jax._host_apply_due
+
+    def watched(st, su, sched, ptr, *args):
+        now = float(st.now)
+        if any(isinstance(ev, VMFailure) and t <= now + 1e-9
+               for t, _, ev in sched[ptr:]):
+            at_kill.append(int(np.max(np.asarray(st.relay_occ))))
+        return real(st, su, sched, ptr, *args)
+
+    monkeypatch.setattr(flowsim_jax, "_host_apply_due", watched)
+    soa = simulate(jobs, faults, engine="soa", seed=seed,
+                   relay_buffer_chunks=cap)
+    got = simulate(jobs, faults, engine="jax", seed=seed,
+                   relay_buffer_chunks=cap)
+    _same(got, soa)
+    assert len(at_kill) == 1 and at_kill[0] >= cap
+    assert got.jobs[0].retried_chunks > 0
+    assert _value("sim.cascade_seq_iters") > 0
 
 
 # ------------------------------------------------------------ IPM counters
@@ -193,7 +395,7 @@ def _op_names(hlo: str) -> set:
 
 
 def test_event_loop_carries_its_scope_names(top):
-    from repro.transfer.events import materialize_jobs, sorted_schedule
+    from repro.transfer.events import sorted_schedule
     from repro.transfer.simconfig import SimConfig
 
     jobs = _direct_jobs(top)
