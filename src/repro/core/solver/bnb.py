@@ -38,6 +38,7 @@ import math
 
 import numpy as np
 
+from repro.obs.metrics import REGISTRY
 from repro.obs.trace import region
 
 from .. import milp
@@ -45,6 +46,9 @@ from ..topology import GBIT_PER_GB
 from .ipm import solve_lp
 
 _INT_TOL = 1e-6
+# LPs the multicast planner solves on the host: the round-down's
+# (``solve_multicast``) and the max-throughput scale probe's
+_mc_host_lps = REGISTRY.counter("planner.mc_host_lps")
 
 
 @dataclasses.dataclass
@@ -539,6 +543,7 @@ def _mc_scale_probe(struct, goals, *, fixed_n=None, fixed_m=None,
     if probe is None:
         return 0.0
     c, A_ub, b_ub, A_eq, b_eq = probe
+    _mc_host_lps.inc()
     res = solve_lp(c, A_ub, b_ub, A_eq, b_eq)
     t = max(float(-(c @ res.x)), 0.0)
     if res.ok:
@@ -581,6 +586,7 @@ def _mc_min_cost(struct, goals, *, fixed_n=None, fixed_m=None, extra_ub=None):
     lp = struct.lp(goals, fixed_n=fixed_n, fixed_m=fixed_m, extra_ub=extra_ub)
     if lp.trivially_infeasible:
         return None
+    _mc_host_lps.inc()
     res = solve_lp(lp.c, lp.A_ub, lp.b_ub, lp.A_eq, lp.b_eq)
     if not _near_ok(res):
         return None
@@ -604,7 +610,10 @@ def solve_multicast(
     uniform-scale probes (max t with every commodity delivering t * goal_d),
     which are always-feasible LPs. Every solve derives O(rows) from the
     cached ``milp.MulticastLPStructure``; ``extra_ub`` rows (degraded links,
-    VM caps) ride on it without any re-assembly.
+    VM caps) ride on it without any re-assembly. Every LP runs on the host
+    (counter ``planner.mc_host_lps``, which the max-throughput probe's
+    scale LPs also feed); the stages are the spans ``bnb.mc_root``,
+    ``bnb.mc_repair``, ``bnb.mc_refit`` and ``bnb.mc_scale``.
     """
     dsts = tuple(int(d) for d in dsts)
     goals = np.asarray(goals, dtype=float)
@@ -622,27 +631,32 @@ def solve_multicast(
         return out
 
     # ---- root relaxation
-    lp = struct.lp(goals, extra_ub=extra_ub)
-    if lp.trivially_infeasible:
-        return _mc_empty(top, len(dsts), "infeasible")
-    root = solve_lp(lp.c, lp.A_ub, lp.b_ub, lp.A_eq, lp.b_eq)
+    with region("bnb.mc_root", track="planner"):
+        lp = struct.lp(goals, extra_ub=extra_ub)
+        if lp.trivially_infeasible:
+            return _mc_empty(top, len(dsts), "infeasible")
+        _mc_host_lps.inc()
+        root = solve_lp(lp.c, lp.A_ub, lp.b_ub, lp.A_eq, lp.b_eq)
     if not _near_ok(root):
         return _mc_empty(top, len(dsts), root.status)
     _, _, n_frac, _ = lp.split(root.x)
 
     # ---- feasibility repair: floor N, bump until the goals are reachable
     n_int, t1 = None, 0.0
-    for n_try in _repair_candidates(n_frac, top.limit_vm):
-        t = _mc_scale_probe(struct, goals, fixed_n=n_try, extra_ub=extra_ub)
-        if t >= 1.0 - 1e-6:
-            n_int, t1 = n_try, t
-            break
+    with region("bnb.mc_repair", track="planner"):
+        for n_try in _repair_candidates(n_frac, top.limit_vm):
+            t = _mc_scale_probe(struct, goals, fixed_n=n_try,
+                                extra_ub=extra_ub)
+            if t >= 1.0 - 1e-6:
+                n_int, t1 = n_try, t
+                break
     if n_int is None:
         return _mc_empty(top, len(dsts), "infeasible", root.fun)
 
     # ---- fixed-N refit: fractional M at the probed-achievable goals
-    fit = _mc_min_cost(struct, goals * min(1.0, t1) * (1.0 - 1e-9),
-                       fixed_n=n_int, extra_ub=extra_ub)
+    with region("bnb.mc_refit", track="planner"):
+        fit = _mc_min_cost(struct, goals * min(1.0, t1) * (1.0 - 1e-9),
+                           fixed_n=n_int, extra_ub=extra_ub)
     if fit is None:
         return _mc_empty(top, len(dsts), "infeasible", root.fun)
     (_, _, _, M_frac), _ = fit
@@ -650,14 +664,15 @@ def solve_multicast(
     _topup_connections(top, M_frac, M_int, n_int)
 
     # ---- fixed-N+M: probe the residual scale, refit G and F at it
-    t2 = _mc_scale_probe(struct, goals, fixed_n=n_int, fixed_m=M_int,
-                         extra_ub=extra_ub)
-    scale = min(1.0, t2) * (1.0 - 1e-9)
-    if scale <= 0.0:
-        return _mc_empty(top, len(dsts), "infeasible", root.fun)
-    achieved = goals * scale
-    fit = _mc_min_cost(struct, achieved, fixed_n=n_int, fixed_m=M_int,
-                       extra_ub=extra_ub)
+    with region("bnb.mc_scale", track="planner"):
+        t2 = _mc_scale_probe(struct, goals, fixed_n=n_int, fixed_m=M_int,
+                             extra_ub=extra_ub)
+        scale = min(1.0, t2) * (1.0 - 1e-9)
+        if scale <= 0.0:
+            return _mc_empty(top, len(dsts), "infeasible", root.fun)
+        achieved = goals * scale
+        fit = _mc_min_cost(struct, achieved, fixed_n=n_int, fixed_m=M_int,
+                           extra_ub=extra_ub)
     if fit is None:
         return _mc_empty(top, len(dsts), "infeasible", root.fun)
     (G, F, _, _), _ = fit

@@ -8,7 +8,9 @@ The fault-tolerant data plane (ISSUE 2) runs several concurrent
     (compounding: ``factor`` multiplies the *current* rates);
   * ``VMFailure``     — gateway VMs of one job die; their in-flight chunks
     are lost and re-dispatched to the surviving workers of the same stage
-    (chunk-level retry, zero data loss while any worker survives);
+    (chunk-level retry). A stage left with no live connection re-opens
+    one between live VMs of its two regions (``reopen_dead_stages``), so
+    no data is lost while every region of the job keeps a live VM;
   * ``GrayFailure``   — the chaos plane's silent partial failure: the same
     rate multiplication as ``LinkDegrade``, but no failure signal — the
     TransferService never folds it into its degraded view, only telemetry
@@ -43,6 +45,8 @@ import numpy as np
 
 from repro.core.plan import MulticastPlan, TransferPlan
 from repro.core.topology import GBIT_PER_GB
+from repro.obs.metrics import REGISTRY
+from repro.obs.trace import region
 
 from .flowsim import conn_efficiency
 
@@ -52,6 +56,12 @@ from .flowsim import conn_efficiency
 # boundary event classified differently on the two sides breaks the
 # chunk-for-chunk equivalence the tests pin.
 T_EPS = 1e-9
+
+# multicast materialization: distribution trees peeled and the stages
+# (tree edges) they became; connections re-opened after VM failures
+_mc_trees = REGISTRY.counter("sim.mc_trees")
+_mc_stages = REGISTRY.counter("sim.mc_stages")
+_conn_reopened = REGISTRY.counter("sim.conn_reopened")
 
 
 @dataclasses.dataclass
@@ -117,10 +127,15 @@ RATE_EVENTS = (LinkDegrade, GrayFailure, LinkRestore)
 
 @dataclasses.dataclass(frozen=True)
 class VMFailure:
-    """At ``t_s``, ``count`` gateway VMs of job ``job`` in ``region`` die.
+    """At ``t_s``, ``count`` gateway VMs of job ``job`` in ``region`` die
+    (the lowest-id live ones).
 
     Connections touching a dead VM are gone for good; chunks they carried
-    return to their stage's ready queue and retry on surviving workers."""
+    return to their stage's ready queue and retry on surviving workers.
+    A stage left with no live connection, whose two regions both still
+    hold a live VM of the job, re-opens one connection between them
+    (``reopen_dead_stages``); one whose region has no live VM stalls the
+    job."""
 
     t_s: float
     job: int  # index into the job list
@@ -229,8 +244,8 @@ class MultiSetup:
     job_slots: list[list[int]]  # per job: its slot ids
     conn_job: np.ndarray  # [NC] all ascending (job, path, hop, conn)
     conn_sid: np.ndarray
-    conn_src: np.ndarray  # global VM ids
-    conn_dst: np.ndarray
+    conn_src: np.ndarray  # global VM ids (``reopen_dead_stages`` re-points
+    conn_dst: np.ndarray  # a lane of a dead stage: the setup is per run)
     conn_rate: np.ndarray  # nominal * straggler multiplier
     conn_edge: np.ndarray  # [NC] index into edges_used
     edges_used: list[tuple[int, int]]
@@ -409,7 +424,9 @@ def materialize_jobs(
             continue
 
         # -------------------------------------------------- multicast job
-        trees = plan.trees()
+        with region("sim.mc_trees", track="sim-host"):
+            trees = plan.trees()
+        _mc_trees.inc(len(trees))
         if not trees:
             raise ValueError(f"job {j} ({job.name!r}) carries no flow")
         served = sorted({d for t in trees for d in t.paths})
@@ -447,6 +464,7 @@ def materialize_jobs(
                 stage_deliver[s_of[e]] = slot_of[d]
             stage_of_edge.append(s_of)
             firsts_j.append([s_of[e] for e in t.roots()])
+            _mc_stages.inc(len(edges))
         first_stage.append(firsts_j)
 
         # ---- connections: the envelope usage of an edge is shared by the
@@ -512,6 +530,45 @@ def materialize_jobs(
         edges_used=edges_used,
         max_hops=max_hops,
     )
+
+
+def reopen_dead_stages(su: MultiSetup, sids, conn_alive, vm_alive) -> int:
+    """The recovery rule after a ``VMFailure``, shared by the array engines.
+
+    Each stage of ``sids`` (ascending) left with no live connection, whose
+    two regions both still hold a live VM of its job, re-opens one: its
+    lowest-index connection comes back alive between, in each region, the
+    live VM with the fewest live connections (lowest id on ties; a
+    re-opened connection counts for the stages after it). It keeps its
+    rate (nominal rate, straggler multiplier and any link events since),
+    so no random draw is made. Mutates ``conn_alive`` and ``su.conn_src``
+    / ``su.conn_dst``; returns the number of connections re-opened."""
+    n = 0
+    nv = su.vm_job.shape[0]
+    for sid in sorted(set(int(s) for s in sids)):
+        lanes = np.flatnonzero(su.conn_sid == sid)
+        if conn_alive[lanes].any():
+            continue
+        ci = int(lanes[0])
+        job = int(su.stage_job[sid])
+        live = su.conn_src[conn_alive], su.conn_dst[conn_alive]
+        load = (np.bincount(live[0], minlength=nv)
+                + np.bincount(live[1], minlength=nv))
+        pick = []
+        for r in su.edges_used[int(su.conn_edge[ci])]:
+            vms = np.flatnonzero(
+                vm_alive & (su.vm_job == job) & (su.vm_region == r)
+            )
+            if not vms.size:
+                break
+            pick.append(int(vms[np.argmin(load[vms])]))
+        if len(pick) < 2:
+            continue  # a region without a live VM: the stage stalls
+        su.conn_src[ci], su.conn_dst[ci] = pick
+        conn_alive[ci] = True
+        n += 1
+    _conn_reopened.inc(n)
+    return n
 
 
 def sorted_schedule(

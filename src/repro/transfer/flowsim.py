@@ -526,8 +526,11 @@ def _simulate_multi_impl(
       * ``events.VMFailure`` kills gateway VMs: their connections die and
         any chunk they carried re-enters its stage queue and retries on a
         surviving connection of the same branch (counted in
-        ``retried_chunks``; a stage whose every connection died stalls the
-        job);
+        ``retried_chunks``). A stage whose every connection died re-opens
+        one between the least-loaded live VMs of its two regions
+        (``events.reopen_dead_stages``: same rate, no random draw); only
+        a stage with a region left without a live VM of the job stalls
+        it;
       * ``horizon_s`` cuts the run (jobs report status "running"). All
         time comparisons share one tolerance (``events.T_EPS``) so a
         boundary event cannot be classified inconsistently.
@@ -612,7 +615,7 @@ def _simulate_multi_impl(
 
     def apply_due():
         nonlocal ptr, last_active
-        from .events import RATE_EVENTS, VMFailure
+        from .events import RATE_EVENTS, VMFailure, reopen_dead_stages
 
         applied_t = None
         rate_n = 0
@@ -653,7 +656,7 @@ def _simulate_multi_impl(
                     )
                     if vm_alive[v]
                 ][: ev.count]
-                requeued = 0
+                requeued = reopened = 0
                 if kill:
                     vm_alive[kill] = False
                     hit = conn_alive & (
@@ -671,10 +674,13 @@ def _simulate_multi_impl(
                             remaining[ci] = 0.0
                             requeued += 1
                     conn_alive[hit] = False
+                    reopened = reopen_dead_stages(
+                        su, sid_arr[hit], conn_alive, vm_alive
+                    )
                 if tr.enabled:
                     tr.instant("sim.vm_failure", t_ev, job=int(ev.job),
                                region=int(ev.region), killed=len(kill),
-                               requeued=requeued)
+                               requeued=requeued, reopened=reopened)
             else:
                 raise TypeError(f"unknown event {ev!r}")
         if applied_t is not None and tr.enabled:

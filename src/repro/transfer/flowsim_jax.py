@@ -7,7 +7,13 @@ on-device under ``lax.while_loop`` over padded structure-of-arrays state
 with validity masks; the host keeps only the scripted schedule — each
 segment of the loop runs until the next due event, the host applies it
 (numpy, the exact reference logic, emitting the same Skytrace stream)
-and re-enters. The max-min water-filling step is the masked pure-jnp
+and re-enters. A ``VMFailure`` is one of those events: the host kills
+the connections, requeues their chunks and, where a stage has no live
+connection left but both its regions keep a live VM of the job, re-opens
+one (``events.reopen_dead_stages``). The lane endpoints are therefore
+operands of ``_segment`` that the host replaces, not constants folded
+into its compile; a stage with a region left without a live VM stalls
+the job. The max-min water-filling step is the masked pure-jnp
 transliteration (``kernels.waterfill.ref.masked_maxmin_rates``, bitwise
 vs the numpy oracle under f64) on CPU, or the f32 Pallas one-hot-matmul
 kernel (``kernels.waterfill``) on TPU backends up to the VMEM size rule
@@ -59,13 +65,14 @@ _INF = float("inf")
 _TRACK = "sim-host"  # the dispatcher's wall spans (the sim track is sim time)
 
 # the dispatcher's work: segments run, scalars of the loop state read back
-# on the host, loop iterations, those that ran the multi-pass cascade, and
-# that cascade's passes
+# on the host, loop iterations, those that ran the multi-pass cascade,
+# that cascade's passes, and chunk copies enqueued to child stages
 _segments = REGISTRY.counter("sim.segments")
 _host_syncs = REGISTRY.counter("sim.host_syncs")
 _loop_iters = REGISTRY.counter("sim.loop_iters")
 _cascade_seq_iters = REGISTRY.counter("sim.cascade_seq_iters")
 _cascade_seq_passes = REGISTRY.counter("sim.cascade_seq_passes")
+_fanout_enqueues = REGISTRY.counter("sim.fanout_enqueues")
 
 
 class _Sc(NamedTuple):
@@ -86,11 +93,12 @@ class _Sc(NamedTuple):
 
 
 class _Cn(NamedTuple):
-    """Per-scenario constants (traced, but never mutated)."""
+    """Per-scenario operands (traced; the device loop never mutates them,
+    the host re-points lane endpoints on a connection re-open)."""
 
     conn_job: jnp.ndarray
     conn_sid: jnp.ndarray
-    conn_src: jnp.ndarray
+    conn_src: jnp.ndarray  # lane endpoints (global VM ids)
     conn_dst: jnp.ndarray
     conn_edge: jnp.ndarray
     conn_valid: jnp.ndarray
@@ -110,7 +118,7 @@ class _Cn(NamedTuple):
     t_eps: jnp.ndarray  # f64 scalar (events.T_EPS)
     one: jnp.ndarray  # f64 1.0, runtime-traced — FMA defeat (see _step)
     # pallas solver operands, [8, lanes] tiles (1-element dummies under
-    # "masked")
+    # "masked"); p_src8/p_dst8 follow conn_src/conn_dst
     p_src8: jnp.ndarray
     p_dst8: jnp.ndarray
     p_eid8: jnp.ndarray
@@ -126,6 +134,7 @@ class _St(NamedTuple):
     events: jnp.ndarray  # iterations that reached the rate step
     seq: jnp.ndarray  # iterations with a relay buffer at capacity
     seq_passes: jnp.ndarray  # stage-batched passes those iterations ran
+    fanout: jnp.ndarray  # chunk copies enqueued to child stages
     draining: jnp.ndarray
     stop: jnp.ndarray  # terminal break reached
     t_sched: jnp.ndarray  # next unapplied scripted event time (+inf)
@@ -363,6 +372,7 @@ def _advance(st: _St, cn: _Cn, sc: _Sc, active, work, jump, events,
     ready_buf, q_tail, relay_occ, enq_bm = (
         st.ready_buf, st.q_tail, st.relay_occ, st.enq_bm
     )
+    fanout = st.fanout
     for k in range(sc.maxch):
         nsid = cn.children[sid, k]
         has = newdone & (nsid >= 0)
@@ -380,6 +390,7 @@ def _advance(st: _St, cn: _Cn, sc: _Sc, active, work, jump, events,
         q_tail = q_tail + cnt
         relay_occ = relay_occ + cnt
         enq_bm = enq_bm.at[row, ch].max(val)
+        fanout = fanout + jnp.sum(vf)
 
     stop = jnp.where(
         adv, horizon_hit | jnp.all(finished),
@@ -392,7 +403,7 @@ def _advance(st: _St, cn: _Cn, sc: _Sc, active, work, jump, events,
         chunk_arr=jnp.where(completed, -1, st.chunk_arr),
         remaining=jnp.where(completed, 0.0, remaining),
         ready_buf=ready_buf, q_tail=q_tail, relay_occ=relay_occ,
-        done_bm=done_bm, enq_bm=enq_bm, delivered=delivered,
+        done_bm=done_bm, enq_bm=enq_bm, fanout=fanout, delivered=delivered,
         finished=finished, finish=finish, jeg=jeg, jeo=jeo, jeb=jeb,
         td_time=td_time, td_job=td_job, td_n=td_n,
     )
@@ -502,13 +513,11 @@ def _build(su, cfg, sched, solver: str):
 
         ncl, nv128 = pad_lanes(ncp), _pad128(nv)
         pall = (
-            lane8(su.conn_src, ncl, 0, np.int32),
-            lane8(su.conn_dst, ncl, 0, np.int32),
             lane8(su.conn_edge, ncl, 0, np.int32),
             lane8(su.vm_eg_cap, nv128, BIG), lane8(su.vm_in_cap, nv128, BIG),
         )
     else:
-        pall = (np.zeros((1, 1), dtype=np.float32),) * 5
+        pall = (np.zeros((1, 1), dtype=np.float32),) * 3
 
     from .events import T_EPS
 
@@ -523,8 +532,7 @@ def _build(su, cfg, sched, solver: str):
     cn = _Cn(
         conn_job=jnp.asarray(padc(su.conn_job, 0)),
         conn_sid=jnp.asarray(padc(su.conn_sid, ns)),
-        conn_src=jnp.asarray(padc(su.conn_src, 0)),
-        conn_dst=jnp.asarray(padc(su.conn_dst, 0)),
+        **_lanes(su, sc),
         conn_edge=jnp.asarray(padc(su.conn_edge, 0)),
         conn_valid=jnp.asarray(np.arange(ncp) < nc),
         chunk_size=jnp.asarray(padc(su.chunk_gbit[su.conn_job], 0.0)),
@@ -544,13 +552,12 @@ def _build(su, cfg, sched, solver: str):
         max_events=jnp.int64(max_events),
         t_eps=jnp.float64(T_EPS),
         one=jnp.float64(1.0),
-        p_src8=jnp.asarray(pall[0]), p_dst8=jnp.asarray(pall[1]),
-        p_eid8=jnp.asarray(pall[2]), p_eg8=jnp.asarray(pall[3]),
-        p_in8=jnp.asarray(pall[4]),
+        p_eid8=jnp.asarray(pall[0]), p_eg8=jnp.asarray(pall[1]),
+        p_in8=jnp.asarray(pall[2]),
     )
     st = _St(
         now=jnp.float64(0.0), it=jnp.int64(0), events=jnp.int64(0),
-        seq=jnp.int64(0), seq_passes=jnp.int64(0),
+        seq=jnp.int64(0), seq_passes=jnp.int64(0), fanout=jnp.int64(0),
         draining=jnp.bool_(False), stop=jnp.bool_(False),
         t_sched=jnp.float64(sched[0][0] if sched else _INF),
         chunk_arr=jnp.full(ncp, -1, dtype=jnp.int64),
@@ -579,11 +586,32 @@ def _build(su, cfg, sched, solver: str):
     return sc, cn, st
 
 
+def _lanes(su, sc: _Sc) -> dict:
+    """The lane endpoint operands of ``_Cn`` from ``su``'s current
+    ``conn_src`` / ``conn_dst`` (padded lanes point at VM 0)."""
+    src = np.zeros(sc.ncp, dtype=np.int64)
+    dst = np.zeros(sc.ncp, dtype=np.int64)
+    nc = su.conn_src.shape[0]
+    src[:nc], dst[:nc] = su.conn_src, su.conn_dst
+    out = {"conn_src": jnp.asarray(src), "conn_dst": jnp.asarray(dst)}
+    if sc.solver == "pallas":
+        from repro.kernels.waterfill.ops import lane8
+        from repro.kernels.waterfill.waterfill import pad_lanes
+
+        ncl = pad_lanes(sc.ncp)
+        out["p_src8"] = jnp.asarray(lane8(su.conn_src, ncl, 0, np.int32))
+        out["p_dst8"] = jnp.asarray(lane8(su.conn_dst, ncl, 0, np.int32))
+    else:
+        out["p_src8"] = out["p_dst8"] = jnp.zeros((1, 1), jnp.float32)
+    return out
+
+
 def _host_apply_due(st: _St, su, sched, ptr, vm_alive, retried, use_edge,
-                    qcap, tr):
+                    cn: _Cn, sc: _Sc, tr):
     """Apply every due scripted event — numpy, the exact reference logic
-    (including its Skytrace instants). Returns (new state, new ptr)."""
-    from .events import RATE_EVENTS, T_EPS, VMFailure
+    (including its Skytrace instants). Returns (new state, new operands,
+    new ptr): a connection re-open re-points lane endpoints."""
+    from .events import RATE_EVENTS, T_EPS, VMFailure, reopen_dead_stages
 
     now = _pull(float, st.now)
     # np.array (copy): np.asarray of a jax array can be a read-only view
@@ -596,6 +624,8 @@ def _host_apply_due(st: _St, su, sched, ptr, vm_alive, retried, use_edge,
         np.array(st.relay_occ), "edge_cap": np.array(st.edge_cap),
     }
     nc = su.conn_job.shape[0]
+    qcap = sc.qcap
+    reopened_any = False
 
     def push(sid, ch):
         h["ready_buf"][sid, h["q_tail"][sid] % qcap] = ch
@@ -633,7 +663,7 @@ def _host_apply_due(st: _St, su, sched, ptr, vm_alive, retried, use_edge,
                 )
                 if vm_alive[v]
             ][: ev.count]
-            requeued = 0
+            requeued = reopened = 0
             if kill:
                 vm_alive[kill] = False
                 hit = h["conn_alive"][:nc] & (
@@ -652,10 +682,14 @@ def _host_apply_due(st: _St, su, sched, ptr, vm_alive, retried, use_edge,
                         requeued += 1
                 ca = h["conn_alive"][:nc]
                 ca[hit] = False
+                reopened = reopen_dead_stages(
+                    su, su.conn_sid[hit], ca, vm_alive
+                )
+                reopened_any |= reopened > 0
             if tr.enabled:
                 tr.instant("sim.vm_failure", t_ev, job=int(ev.job),
                            region=int(ev.region), killed=len(kill),
-                           requeued=requeued)
+                           requeued=requeued, reopened=reopened)
         else:
             raise TypeError(f"unknown event {ev!r}")
     if applied_t is not None and tr.enabled:
@@ -681,10 +715,12 @@ def _host_apply_due(st: _St, su, sched, ptr, vm_alive, retried, use_edge,
             edge_cap=jnp.asarray(h["edge_cap"]),
             rates_valid=jnp.bool_(False),  # events invalidate the cache
         )
+    if reopened_any:
+        cn = cn._replace(**_lanes(su, sc))
     st = st._replace(
         t_sched=jnp.float64(sched[ptr][0] if ptr < len(sched) else _INF)
     )
-    return st, ptr
+    return st, cn, ptr
 
 
 def _finalize(st: _St, su, jobs, cfg, retried, tr):
@@ -696,17 +732,18 @@ def _finalize(st: _St, su, jobs, cfg, retried, tr):
     ne = len(su.edges_used)
     nc = su.conn_job.shape[0]
     # one transfer of everything the accounting reads
-    (now, events, it, seq, seq_passes, chunk_arr, arrived, finished,
+    (now, events, it, seq, seq_passes, fanout, chunk_arr, arrived, finished,
      finish_t, delivered, job_edge_gbit, job_edge_obs_gbit,
      job_edge_busy) = jax.device_get((
-         st.now, st.events, st.it, st.seq, st.seq_passes, st.chunk_arr,
-         st.arrived, st.finished, st.finish, st.delivered, st.jeg, st.jeo,
-         st.jeb))
+         st.now, st.events, st.it, st.seq, st.seq_passes, st.fanout,
+         st.chunk_arr, st.arrived, st.finished, st.finish, st.delivered,
+         st.jeg, st.jeo, st.jeb))
     now = float(now)
     chunk_arr = chunk_arr[:nc]
     _loop_iters.inc(int(it))
     _cascade_seq_iters.inc(int(seq))
     _cascade_seq_passes.inc(int(seq_passes))
+    _fanout_enqueues.inc(int(fanout))
     horizon_s = cfg.horizon_s
 
     horizon_cut = horizon_s is not None and now >= horizon_s - T_EPS
@@ -852,9 +889,9 @@ def simulate_multi_jax(
             while True:
                 if not _pull(bool, st.draining):
                     with region("sim.apply_due", track=_TRACK):
-                        st, ptr = _host_apply_due(
+                        st, cn, ptr = _host_apply_due(
                             st, su, sched, ptr, vm_alive, retried,
-                            cfg.link_capacity_scale is not None, sc.qcap, tr,
+                            cfg.link_capacity_scale is not None, cn, sc, tr,
                         )
                 _segments.inc()
                 with region("sim.segment", track=_TRACK):

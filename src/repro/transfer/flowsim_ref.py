@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core.plan import TransferPlan
 from repro.core.topology import GBIT_PER_GB
+from repro.obs.metrics import REGISTRY
 from repro.obs.trace import get_tracer
 
 from .flowsim import SimResult, conn_efficiency
@@ -25,6 +26,7 @@ from .simconfig import resolve as resolve_sim_config
 from .simconfig import warn_deprecated_entry as _warn_deprecated_entry
 
 _EPS = 1e-12
+_conn_reopened = REGISTRY.counter("sim.conn_reopened")
 
 
 @dataclasses.dataclass
@@ -477,7 +479,16 @@ def _simulate_multi_reference_impl(
     multicast jobs (tree fan-out, per-destination delivery slots). The
     vectorized loop must reproduce its per-job delivered-chunk counts
     exactly (``exec_top`` included: the believed/true grid split changes
-    rates, not materialization order)."""
+    rates, not materialization order).
+
+    A ``VMFailure`` kills the lowest-id live VMs of the job in its region;
+    their connections die and carried chunks re-enter the stage queue. A
+    stage left with no live connection then re-opens its lowest-index
+    connection, at its rate, between the live VMs of its two regions that
+    carry the fewest live connections (lowest id on ties), stage by stage
+    in ascending order; a stage with a region left without a live VM of
+    the job stalls it. The vectorized engines share this rule through
+    ``events.reopen_dead_stages``; it is restated here on objects."""
     from .events import RATE_EVENTS, T_EPS, JobSimResult, MultiSimResult
     from .events import VMFailure, materialize_jobs, sorted_schedule
     from repro.core.plan import MulticastPlan
@@ -570,11 +581,12 @@ def _simulate_multi_reference_impl(
                     if vm_alive[v] and su.vm_job[v] == ev.job
                     and su.vm_region[v] == ev.region
                 ][: ev.count]
-                requeued = 0
+                requeued = reopened = 0
                 if kill:
                     for v in kill:
                         vm_alive[v] = False
                     killset = set(kill)
+                    hit_sids = set()
                     for ci, c in enumerate(conns):
                         if not c.alive:
                             continue
@@ -590,10 +602,14 @@ def _simulate_multi_reference_impl(
                                 c.remaining = 0.0
                                 requeued += 1
                             c.alive = False
+                            hit_sids.add(c.sid)
+                    for sid in sorted(hit_sids):
+                        reopened += reopen(sid)
+                    _conn_reopened.inc(reopened)
                 if tr.enabled:
                     tr.instant("sim.vm_failure", t_ev, job=int(ev.job),
                                region=int(ev.region), killed=len(kill),
-                               requeued=requeued)
+                               requeued=requeued, reopened=reopened)
             else:
                 raise TypeError(f"unknown event {ev!r}")
         if applied_t is not None and tr.enabled:
@@ -607,6 +623,31 @@ def _simulate_multi_reference_impl(
             for i, (a, b) in enumerate(su.edges_used):
                 if counts[i]:
                     tr.sample(f"link {a}->{b}", applied_t, counts[i])
+
+    def reopen(sid: int) -> int:
+        """Re-open the dead stage ``sid`` if both its regions keep a live
+        VM of the job; 1 if it did."""
+        mine = [c for c in conns if c.sid == sid]
+        if any(c.alive for c in mine):
+            return 0
+        c = mine[0]
+        a, b = su.edges_used[c.edge_ix]
+        load = {}
+        for o in conns:
+            if o.alive:
+                load[o.src_vm] = load.get(o.src_vm, 0) + 1
+                load[o.dst_vm] = load.get(o.dst_vm, 0) + 1
+        pick = []
+        for r in (a, b):
+            vms = [v for v in range(len(vm_alive))
+                   if vm_alive[v] and su.vm_job[v] == c.job
+                   and su.vm_region[v] == r]
+            if not vms:
+                return 0
+            pick.append(min(vms, key=lambda v: (load.get(v, 0), v)))
+        c.src_vm, c.dst_vm = pick
+        c.alive = True
+        return 1
 
     def refill(ci: int) -> bool:
         c = conns[ci]

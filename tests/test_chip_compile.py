@@ -110,7 +110,8 @@ def test_batched_ipm_compiles_at_the_fig6_sweep_shape(one_chip, top):
 
 def _scenarios(top):
     """chip_smoke's two sim shapes: one bulk direct-plan job of 1e5 64 MB
-    chunks, and overlay jobs (relay fan-out) with a VM kill."""
+    chunks, and overlay jobs (relay fan-out) with a VM kill; and a
+    multicast job (tree fan-out to several children) with a VM kill."""
     bulk = [TransferJob(
         direct_plan(top, "aws:us-west-2", "aws:eu-central-1", 100_000 / 16,
                     num_vms=2),
@@ -123,10 +124,17 @@ def _scenarios(top):
     ))
     overlay = [TransferJob(plan, "a"), TransferJob(plan, "b", arrival_s=0.5)]
     kill = [VMFailure(t_s=2.0, job=0, region=top.index(SRC), count=1)]
-    return {"bulk": (bulk, []), "overlay": (overlay, kill)}
+    mc = Planner(top, max_relays=6).plan(PlanSpec(
+        objective="cost_min", src="gcp:us-central1",
+        dsts=("gcp:europe-west1", "gcp:europe-west3", "gcp:europe-west4"),
+        tput_goal_gbps=2.0, volume_gb=4.0,
+    ))
+    mc_kill = [VMFailure(t_s=1.5, job=0, region=mc.dsts[0], count=1)]
+    return {"bulk": (bulk, []), "overlay": (overlay, kill),
+            "multicast": ([TransferJob(mc, "repl")], mc_kill)}
 
 
-@pytest.mark.parametrize("name", ["bulk", "overlay"])
+@pytest.mark.parametrize("name", ["bulk", "overlay", "multicast"])
 def test_sim_segment_compiles_with_the_chip_rate_solver(one_chip, top, name,
                                                         monkeypatch):
     from repro.kernels.waterfill import ops

@@ -105,15 +105,25 @@ def assert_parity(out, traces):
         assert da == db
 
     # the sim-time Skytrace stream is engine-independent, tuple for tuple;
-    # the jax engine adds its dispatcher's wall spans on a track of their own
-    assert traces["soa"] == traces["ref"]
-    assert [e for e in traces["jax"] if e[4] == "sim"] == traces["ref"]
+    # wall spans ride a track of their own: the jax engine's dispatcher
+    # spans, and in every engine the shared materialization's multicast
+    # tree peel
+    def sim(tr):
+        return [e for e in tr if e[4] == "sim"]
+
+    assert sim(traces["soa"]) == sim(traces["ref"])
+    assert sim(traces["jax"]) == sim(traces["ref"])
+    peel = [e for e in traces["ref"] if e[4] != "sim"]
+    assert [e[:2] for e in traces["soa"] if e[4] != "sim"] == [
+        e[:2] for e in peel
+    ]
+    assert {e[1] for e in peel} <= {"sim.mc_trees"}
     host = [e for e in traces["jax"] if e[4] != "sim"]
     assert {(e[0], e[4]) for e in host} == {("X", "sim-host")}
     assert {e[1] for e in host} == {
         "sim.run", "sim.materialize", "sim.build", "sim.apply_due",
         "sim.segment", "sim.finalize",
-    }
+    } | {e[1] for e in peel}
 
 
 def test_plain_three_jobs(top):
