@@ -37,9 +37,14 @@ Exact-semantics notes (each is load-bearing for chunk-for-chunk parity):
     telemetry segment-sums — the multiple use (plus living inside
     ``lax.while_loop``) keeps LLVM from contracting the multiply-subtract
     into an FMA, which would break last-ulp parity with numpy;
-  * segment-sums over masked lanes add interspersed ``+0.0`` terms to the
-    reference bincounts, which cannot change an IEEE sum; masked minima
-    pad with ``+inf``, which never wins.
+  * the loop's counts (stage takes and enqueues, deliveries, unmet
+    slots) are dense one-hot reductions in int32, exact in any order, and
+    the per-(job, edge) activity is a boolean any; the one float sum, the
+    per-(job, edge) Gbit moved, keeps ``np.bincount`` order under
+    ``"masked"`` (a segment-sum over masked lanes adds interspersed
+    ``+0.0`` terms, which cannot change an IEEE sum) and is a dense
+    reduction under ``"pallas"``; masked minima pad with ``+inf``, which
+    never wins.
 """
 
 from __future__ import annotations
@@ -164,6 +169,25 @@ class _St(NamedTuple):
     td_n: jnp.ndarray
 
 
+def _onehot(idx, n):
+    """``[lanes, n]`` bool: lane ``i`` is in segment ``idx[i]`` (an ``idx``
+    outside ``[0, n)`` is in none). Compared in int32: the ids are small
+    and the TPU emulates i64."""
+    return idx.astype(jnp.int32)[:, None] == jnp.arange(n, dtype=jnp.int32)
+
+
+def _onehot_sum(v, idx, n):
+    """``segment_sum(v, idx, n)`` as a dense compare-and-reduce over
+    ``_onehot(idx, n)``. The TPU runs a scatter-add one update at a time;
+    this is a few vector ops. A bool ``v`` counts its true lanes in int32,
+    exact in any order (a count never exceeds the lane count). A float
+    ``v`` adds in the reduction's order, not ``np.bincount``'s."""
+    hit = _onehot(idx, n)
+    if v.dtype == jnp.bool_:
+        return jnp.sum(hit & v[:, None], axis=0, dtype=jnp.int32)
+    return jnp.sum(jnp.where(hit, v[:, None], 0), axis=0)
+
+
 def _compute_rates(st: _St, cn: _Cn, sc: _Sc, active):
     if sc.solver == "pallas":
         from repro.kernels.waterfill.ops import _interpret, _pad128
@@ -221,7 +245,7 @@ def _cascade_batch(small, st: _St, cn: _Cn, sc: _Sc, run, blocked=None):
     row = jnp.where(take, cn.conn_sid, sc.ns)
     pos = (q_head[row] + rank) % sc.qcap
     ch = st.ready_buf[row, jnp.where(take, pos, 0)]
-    cnt = segment_sum(take.astype(i64), row, num_segments=sc.ns + 1)
+    cnt = _onehot_sum(take, row, sc.ns + 1)
     return (
         jnp.where(take, ch, chunk_arr),
         jnp.where(take, cn.chunk_size, remaining),
@@ -335,11 +359,15 @@ def _advance(st: _St, cn: _Cn, sc: _Sc, active, work, jump, events,
     remaining = jnp.where(act_adv, st.remaining - moved, st.remaining)
     w = jnp.where(act_adv, moved, 0.0)
     je = cn.conn_job * sc.ne + cn.conn_edge
-    seg = segment_sum(w, je, num_segments=sc.j * sc.ne)
+    nje = sc.j * sc.ne
+    # the one float sum: np.bincount's order (bitwise parity) under
+    # "masked"; dense under "pallas", whose f32 rates are not bitwise
+    if sc.solver == "pallas":
+        seg = _onehot_sum(w, je, nje)
+    else:
+        seg = segment_sum(w, je, num_segments=nje)
     jeg = jnp.where(adv, st.jeg + seg, st.jeg)
-    je_on = segment_sum(
-        act_adv.astype(w.dtype), je, num_segments=sc.j * sc.ne
-    ) > 0
+    je_on = jnp.any(act_adv[:, None] & _onehot(je, nje), axis=0)
     jeo = jnp.where(adv & obs_live, st.jeo + seg, st.jeo)
     jeb = jnp.where(adv & obs_live & je_on, st.jeb + dt, st.jeb)
 
@@ -352,13 +380,9 @@ def _advance(st: _St, cn: _Cn, sc: _Sc, active, work, jump, events,
     done_bm = st.done_bm.at[sid, ch].max(newdone)
     slot = cn.stage_deliver[sid]
     sval = newdone & (slot >= 0)
-    delivered = st.delivered + segment_sum(
-        sval.astype(i64), jnp.maximum(slot, 0), num_segments=sc.nslot
-    )
+    delivered = st.delivered + _onehot_sum(sval, slot, sc.nslot)
     ok_slot = delivered >= cn.slot_need
-    bad = segment_sum(
-        (~ok_slot).astype(i64), cn.slot_job, num_segments=sc.j
-    )
+    bad = _onehot_sum(~ok_slot, cn.slot_job, sc.j)
     job_ok = adv & (bad == 0)
     newly = job_ok & ~st.finished
     finished = st.finished | job_ok
@@ -386,7 +410,7 @@ def _advance(st: _St, cn: _Cn, sc: _Sc, active, work, jump, events,
         ready_buf = ready_buf.at[row, pos].set(
             jnp.where(val, ch, ready_buf[row, pos])
         )
-        cnt = segment_sum(vf, row, num_segments=sc.ns + 1)
+        cnt = _onehot_sum(val, row, sc.ns + 1)
         q_tail = q_tail + cnt
         relay_occ = relay_occ + cnt
         enq_bm = enq_bm.at[row, ch].max(val)

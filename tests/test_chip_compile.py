@@ -21,10 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Planner, PlanSpec, default_topology, direct_plan, milp
-from repro.transfer import TransferJob, VMFailure
-
-SRC, DST = "azure:canadacentral", "gcp:asia-northeast1"  # chip_smoke route
+from _sim_scenarios import SRC, DST, sim_scenarios
+from repro.core import Planner, default_topology, milp
 
 
 @pytest.fixture(scope="module")
@@ -108,32 +106,6 @@ def test_batched_ipm_compiles_at_the_fig6_sweep_shape(one_chip, top):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
 
 
-def _scenarios(top):
-    """chip_smoke's two sim shapes: one bulk direct-plan job of 1e5 64 MB
-    chunks, and overlay jobs (relay fan-out) with a VM kill; and a
-    multicast job (tree fan-out to several children) with a VM kill."""
-    bulk = [TransferJob(
-        direct_plan(top, "aws:us-west-2", "aws:eu-central-1", 100_000 / 16,
-                    num_vms=2),
-        "bulk", chunk_mb=64.0,
-    )]
-    ceiling = direct_plan(top, SRC, DST, 16.0).cost_per_gb * 1.15
-    plan = Planner(top).plan(PlanSpec(
-        objective="tput_max", src=SRC, dst=DST, volume_gb=16.0,
-        cost_ceiling_per_gb=ceiling, n_samples=8,
-    ))
-    overlay = [TransferJob(plan, "a"), TransferJob(plan, "b", arrival_s=0.5)]
-    kill = [VMFailure(t_s=2.0, job=0, region=top.index(SRC), count=1)]
-    mc = Planner(top, max_relays=6).plan(PlanSpec(
-        objective="cost_min", src="gcp:us-central1",
-        dsts=("gcp:europe-west1", "gcp:europe-west3", "gcp:europe-west4"),
-        tput_goal_gbps=2.0, volume_gb=4.0,
-    ))
-    mc_kill = [VMFailure(t_s=1.5, job=0, region=mc.dsts[0], count=1)]
-    return {"bulk": (bulk, []), "overlay": (overlay, kill),
-            "multicast": ([TransferJob(mc, "repl")], mc_kill)}
-
-
 @pytest.mark.parametrize("name", ["bulk", "overlay", "multicast"])
 def test_sim_segment_compiles_with_the_chip_rate_solver(one_chip, top, name,
                                                         monkeypatch):
@@ -142,7 +114,7 @@ def test_sim_segment_compiles_with_the_chip_rate_solver(one_chip, top, name,
     from repro.transfer.events import materialize_jobs, sorted_schedule
     from repro.transfer.simconfig import SimConfig
 
-    jobs, faults = _scenarios(top)[name]
+    jobs, faults = sim_scenarios(top)[name]
     cfg = SimConfig()
     su = materialize_jobs(
         jobs, seed=cfg.seed, straggler_prob=cfg.straggler_prob,

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _sim_scenarios import sim_scenarios
 from repro.core import (
     Planner,
     PlanSpec,
@@ -408,6 +409,55 @@ def test_event_loop_carries_its_scope_names(top):
     for scope in ("cascade_batch", "cascade_seq", "rate_solve",
                   "state_update"):
         assert any(f"/{scope}/" in n for n in names), scope
+
+
+def _scatter_adds(hlo: str) -> list[tuple[str, str]]:
+    """(type, op_name) of each scatter in ``hlo`` whose update
+    computation adds."""
+    root_op, comp, found = {}, None, []
+    for line in hlo.splitlines():
+        if head := re.match(r"(?:ENTRY )?(\S+) .*\{$", line):
+            comp = head.group(1)
+        elif root := re.match(r"\s*ROOT \S+ = .*? ([a-z][\w-]*)\(", line):
+            root_op[comp] = root.group(1)
+        elif sc := re.search(r"= (\S+) scatter\(.*to_apply=([^,\s]+)",
+                             line):
+            name = re.search(r'op_name="([^"]*)"', line)
+            found.append((sc.group(1), sc.group(2),
+                          name.group(1) if name else ""))
+    return [(t, n) for t, to_apply, n in found if root_op[to_apply] == "add"]
+
+
+@pytest.fixture(scope="module")
+def scenarios(top):
+    return sim_scenarios(top)
+
+
+@pytest.mark.parametrize("name", ["bulk", "overlay", "multicast"])
+def test_event_loop_sums_without_scatter_adds(scenarios, name):
+    """The loop's segment sums are dense one-hot reductions. Lowered with
+    the TPU's rate solver (the Pallas kernel, interpreted here) no
+    scatter-add is left; under "masked" the one outside the rate solve
+    (the masked solver's own sums) is the f64 per-(job, edge) Gbit moved,
+    kept in ``np.bincount`` order for bitwise parity with soa."""
+    from repro.transfer.events import sorted_schedule
+    from repro.transfer.simconfig import SimConfig
+
+    jobs, faults = scenarios[name]
+    su = materialize_jobs(jobs, seed=0)
+    adds = {}
+    with jax.enable_x64(True):
+        for solver in ("pallas", "masked"):
+            sc, cn, st = flowsim_jax._build(
+                su, SimConfig(), sorted_schedule(jobs, faults), solver)
+            assert (sc.maxch > 0) is (name != "bulk")
+            adds[solver] = _scatter_adds(flowsim_jax._segment.lower(
+                st, cn, sc).as_text(dialect="hlo", debug_info=True))
+    assert adds["pallas"] == []
+    loop = [(t, n) for t, n in adds["masked"] if "/rate_solve/" not in n]
+    assert len(loop) == 1, loop
+    ((typ, op_name),) = loop
+    assert typ.startswith("f64[") and "/state_update/" in op_name
 
 
 def test_device_ipm_carries_its_scope_names():

@@ -28,6 +28,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from _sim_scenarios import sim_scenarios
 from repro.core import Planner, PlanSpec, default_topology, direct_plan, milp
 from repro.obs import trace
 from repro.transfer import (
@@ -241,6 +242,63 @@ def test_pallas_rate_solver_matches_soa_outcomes(top):
     for a, b in zip(got.jobs, soa.jobs):
         assert (a.status, a.chunks_delivered) == (b.status, b.chunks_delivered)
         assert a.time_s == pytest.approx(b.time_s, rel=1e-5)
+
+
+@pytest.fixture(scope="module")
+def scenarios(top):
+    return sim_scenarios(top)
+
+
+@pytest.mark.parametrize("name", ["overlay", "multicast"])
+def test_pallas_event_loop_matches_masked_under_a_vm_kill(scenarios, name):
+    """The TPU path's loop (f32 Pallas rates, interpreted here, and the
+    dense f64 per-(job, edge) sum that the bitwise soa pins never run)
+    gives the masked loop's discrete outcomes; times and per-edge volumes
+    agree to the kernel's f32 tolerance."""
+    from repro.transfer.flowsim_jax import simulate_multi_jax
+
+    jobs, faults = scenarios[name]
+    masked = simulate_multi_jax(jobs, faults, seed=0, _rate_solver="masked")
+    got = simulate_multi_jax(jobs, faults, seed=0, _rate_solver="pallas")
+    assert sum(j.retried_chunks for j in got.jobs) > 0  # the kill lands
+    assert got.events == masked.events
+    for a, b in zip(got.jobs, masked.jobs):
+        assert (a.status, a.chunks_delivered, a.per_dst_delivered) == (
+            b.status, b.chunks_delivered, b.per_dst_delivered)
+        assert a.time_s == pytest.approx(b.time_s, rel=1e-5)
+        assert a.per_edge_gb.keys() == b.per_edge_gb.keys()
+        assert a.per_edge_gb == pytest.approx(b.per_edge_gb, rel=1e-5)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_onehot_sum_matches_segment_sum(seed):
+    """The loop's dense reduction against ``jax.ops.segment_sum``: masked
+    lanes parked on the dump row ``ns``, ids outside the segments (an
+    undelivered slot's -1) and empty segments. Counts are exact; the f64
+    sum adds in another order."""
+    import jax
+    from jax.ops import segment_sum
+
+    from repro.transfer.flowsim_jax import _onehot_sum
+
+    rng = np.random.default_rng([16, seed])
+    lanes, ns = int(rng.integers(1, 3000)), int(rng.integers(1, 80))
+    used = rng.choice(ns, size=max(1, ns // 2), replace=False)
+    mask = rng.random(lanes) < 0.7
+    idx = np.where(mask, rng.choice(used, lanes), ns)
+    idx[rng.random(lanes) < 0.05] = -1
+    w = np.where(mask, rng.exponential(size=lanes), 0.0)
+    with jax.enable_x64(True):
+        idx_j = jax.numpy.asarray(idx)
+        cnt = np.asarray(_onehot_sum(jax.numpy.asarray(mask), idx_j, ns + 1))
+        want = np.asarray(segment_sum(mask.astype(np.int64), idx_j, ns + 1))
+        got_f = np.asarray(_onehot_sum(jax.numpy.asarray(w), idx_j, ns + 1))
+        want_f = np.asarray(segment_sum(w, idx_j, ns + 1))
+    assert cnt.dtype == np.int32 and np.array_equal(cnt, want)
+    empty = np.setdiff1d(np.arange(ns), used)
+    assert cnt[ns] == 0 and (cnt[empty] == 0).all()
+    assert got_f.dtype == np.float64
+    np.testing.assert_allclose(got_f, want_f, rtol=1e-12, atol=0)
 
 
 def test_rate_solver_rule():
